@@ -16,7 +16,7 @@ Five sections, one per acceptance claim:
   T-leaf model.  The tree walk issues T·2L collective-permutes per
   round, the fused path exactly 2L (one flat row per slot) at
   identical wire bytes — and the per-round wall time follows
-  (interleaved medians, ``speedup = tree_ms / flat_ms``);
+  (interleaved medians on the CPU, ``cpu_speedup = tree_ms / flat_ms``);
 * ``mix_fusion_memory`` — XLA ``memory_analysis`` temp bytes for the
   two compiled global programs, when the backend reports it;
 * ``mix_fusion_codec`` (also runnable alone via ``--codec``) — the wire
@@ -33,6 +33,12 @@ device, so the fused path's win there is program structure, not CPU
 milliseconds; the wall-clock win shows on the collective-bound
 shard_map round (and, on real TPUs, in the kernel's (K+1)·N HBM
 traffic).  Quick mode keeps every section seconds-fast.
+
+The ``mix_fusion_round`` and ``mix_fusion_codec`` subprocesses are
+host-device collective-counting probes: the child runs with
+``JAX_PLATFORMS=cpu`` on 8 forced host devices, so on a chip machine it
+never reaches for the chip the parent process holds, and its times are
+CPU times, not device times.
 """
 
 from __future__ import annotations
@@ -96,7 +102,7 @@ _ROUND_PROBE = textwrap.dedent("""
             jax.block_until_ready(f(tree, W, S))
             ts[k].append(time.perf_counter() - t0)
     for row in rows:
-        row["per_round_ms"] = round(
+        row["cpu_round_ms"] = round(
             float(np.median(ts[row["path"]])) * 1e3, 3)
     print(json.dumps(rows))
 """)
@@ -176,7 +182,7 @@ _CODEC_PROBE = textwrap.dedent("""
             jax.block_until_ready(call(k))
             ts[k].append(time.perf_counter() - t0)
     for row in rows:
-        row["per_round_ms"] = round(
+        row["cpu_round_ms"] = round(
             float(np.median(ts[row["codec"]])) * 1e3, 3)
     print(json.dumps(rows))
 """)
@@ -278,6 +284,7 @@ def _round_section(quick: bool) -> None:
            "leaf": 512 if quick else 4096, "reps": 8 if quick else 20}
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)              # the probe forces its own
+    env["JAX_PLATFORMS"] = "cpu"            # host devices, never the chip
     repo = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
     src = os.path.join(repo, "src")
     env["PYTHONPATH"] = src + (
@@ -289,13 +296,13 @@ def _round_section(quick: bool) -> None:
         raise RuntimeError(f"round probe failed:\n{res.stderr[-2000:]}")
     rows = json.loads(res.stdout.strip().splitlines()[-1])
     by_path = {r["path"]: r for r in rows}
-    speedup = (by_path["tree"]["per_round_ms"]
-               / by_path["flat"]["per_round_ms"])
+    speedup = (by_path["tree"]["cpu_round_ms"]
+               / by_path["flat"]["cpu_round_ms"])
     for r in rows:
         emit("mix_fusion_round", spaces=cfg["spaces"],
              leaves=cfg["leaves"], leaf_dim=cfg["leaf"], **{
                  k: v for k, v in r.items() if k != "path"},
-             path=r["path"], speedup=round(speedup, 2))
+             path=r["path"], cpu_speedup=round(speedup, 2))
 
 
 def _memory_section(quick: bool) -> None:
@@ -324,6 +331,7 @@ def _codec_section(quick: bool) -> None:
            "codecs": [None, "bf16", "int8-block", "int4-block", "topk"]}
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)              # the probe forces its own
+    env["JAX_PLATFORMS"] = "cpu"            # host devices, never the chip
     repo = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
     src = os.path.join(repo, "src")
     env["PYTHONPATH"] = src + (
@@ -341,7 +349,7 @@ def _codec_section(quick: bool) -> None:
              codec=r["codec"], ppermutes=r["ppermutes"],
              wire_mb=r["wire_mb"],
              predicted_wire_mb=r["predicted_wire_mb"],
-             per_round_ms=r["per_round_ms"],
+             cpu_round_ms=r["cpu_round_ms"],
              wire_reduction=round(
                  base["wire_mb"] / r["wire_mb"], 2)
              if r["wire_mb"] > 0 else -1,

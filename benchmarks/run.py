@@ -19,7 +19,7 @@ against the committed ``git HEAD`` copy of each ``BENCH_<name>.json``
 (falling back to the artifact on disk when untracked) and exits nonzero
 when any perf field regresses by more than 25% (lower-is-better
 fields: ``seconds`` / ``*_ms``; higher-is-better: ``*_per_s`` /
-``speedup``; rows are matched by their non-perf identity fields).
+``*speedup``; rows are matched by their non-perf identity fields).
 """
 
 from __future__ import annotations
@@ -34,6 +34,7 @@ import traceback
 from typing import Dict, List, Optional, Tuple
 
 from repro import obs
+from repro.launch.compile_cache import enable_compile_cache
 
 from . import (churn_swap, cohort_stream, common, crosspod, fault_storm,
                fig3_topology, fig8_churn, fig11_noniid, fig12_async,
@@ -117,7 +118,7 @@ def perf_direction(key: str) -> Optional[int]:
     if (key == "seconds" or key.endswith("_ms") or key.endswith("_bytes")
             or key.endswith("_mb")):
         return -1
-    if (key == "speedup" or key.endswith("_per_s")
+    if (key.endswith("speedup") or key.endswith("_per_s")
             or key.endswith("_reduction")):
         return +1
     return None
@@ -233,6 +234,7 @@ def main() -> int:
     args = ap.parse_args()
     if args.baseline:
         args.json = True
+    enable_compile_cache()
 
     names = list(MODULES) if not args.only else args.only.split(",")
     unknown = [n for n in names if n not in MODULES]

@@ -17,6 +17,10 @@ form against the HLO-measured collective-permute bytes (the small
 residual gap is the FlatSpec 128-lane padding, which the closed form
 prices at the unpadded element count).
 
+The subprocess is a host-device collective-counting probe: it runs with
+``JAX_PLATFORMS=cpu`` on 8 forced host devices, so on a chip machine it
+never reaches for the chip the parent process holds.
+
   PYTHONPATH=src python -m benchmarks.sync_collectives \
       [--clients-per-device 1,2,4] [--quick]
 """
@@ -139,6 +143,7 @@ def run(quick: bool = False,
     env = dict(os.environ)
     env["PYTHONPATH"] = env.get("PYTHONPATH", "src")
     env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"       # host devices, never the chip
     res = subprocess.run(
         [sys.executable, "-c", _PROBE, json.dumps(cfg)], env=env,
         capture_output=True, text=True, timeout=600)

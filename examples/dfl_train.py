@@ -1,27 +1,30 @@
 """End-to-end DFL training on the TPU path (deliverable b: the e2e
-driver).  Eight FedLay clients — one per device — train a small LM on
-non-iid token shards for a few hundred steps; model sync is the paper's
-2L-ppermute FedLay mixing.  Compare against centralized all-reduce:
+driver).  FedLay clients — ``--clients-per-device`` on every device —
+train a small LM on non-iid token shards for a few hundred steps; model
+sync is the paper's 2L-ppermute FedLay mixing.  Compare against
+centralized all-reduce:
 
   python examples/dfl_train.py --steps 300
   python examples/dfl_train.py --steps 300 --sync allreduce
+
+On the CPU, 8 forced host devices stand in for chips:
+
+  JAX_PLATFORMS=cpu python examples/dfl_train.py --steps 300
 """
 
 import os
+import sys
 
-os.environ.setdefault("XLA_FLAGS",
-                      "--xla_force_host_platform_device_count=8")
-
-import argparse  # noqa: E402
-import sys  # noqa: E402
+if os.environ.get("JAX_PLATFORMS") == "cpu":
+    os.environ.setdefault("XLA_FLAGS",
+                          "--xla_force_host_platform_device_count=8")
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.launch.train import main as train_main  # noqa: E402
 
 if __name__ == "__main__":
-    if "--clients" not in sys.argv:
-        sys.argv += ["--clients", "8"]
+    # --clients defaults to --clients-per-device × devices
     if "--steps" not in sys.argv:
         sys.argv += ["--steps", "300"]
     sys.exit(train_main())

@@ -425,21 +425,28 @@ class Simulator:
         self._arm_timers(node_id)
         self._schedule(self.now + self.probe_period, ("timer", node_id, "join_retry"))
 
+    def _entry(self, st: NodeState) -> Optional[int]:
+        """The contact a joining node routes through: its bootstrap while
+        alive, else a known peer, else a live rendezvous seed, else the
+        lowest-id live member (the rendezvous service of last resort, so
+        a joiner whose every contact failed still gets in)."""
+        if st.bootstrap is not None and st.bootstrap in self.nodes \
+                and self.nodes[st.bootstrap].alive:
+            return st.bootstrap
+        if st.addr_book:
+            return sorted(st.addr_book)[0]
+        for s in st.seeds:              # rendezvous fallback
+            if s in self.nodes and self.nodes[s].alive:
+                return s
+        return min((u for u, n in self.nodes.items()
+                    if n.alive and n.joined and u != st.node_id),
+                   default=None)
+
     def _send_discoveries(self, st: NodeState, all_spaces: bool = False) -> None:
         """(Re)issue Neighbor_discovery for every space still missing a
         pointer — joins are retried until they succeed, so discovery
         messages dropped at failed relays are not fatal."""
-        entry = None
-        if st.bootstrap is not None and st.bootstrap in self.nodes \
-                and self.nodes[st.bootstrap].alive:
-            entry = st.bootstrap
-        if entry is None and st.addr_book:
-            entry = sorted(st.addr_book)[0]
-        if entry is None:
-            for s in st.seeds:          # rendezvous fallback
-                if s in self.nodes and self.nodes[s].alive:
-                    entry = s
-                    break
+        entry = self._entry(st)
         if entry is None:
             return
         for s in range(self.num_spaces):
@@ -519,6 +526,16 @@ class Simulator:
     def _on_discovery(self, node: NodeState, msg: Discovery) -> None:
         s, x = msg.space, msg.target
         if msg.hops >= self.max_hops:
+            return
+        if not node.joined:
+            # a node still joining is not in the overlay: splicing the
+            # joiner here would make a private island of the two, so
+            # pass the discovery on through this node's own contact
+            entry = self._entry(node)
+            if entry is not None and entry != msg.joiner:
+                self.send(node.node_id, entry,
+                          dataclasses.replace(msg, hops=msg.hops + 1),
+                          join_phase=True)
             return
         best, best_cd = None, circular_distance(node.coords[s], x)
         for w, wc in node.addr_book.items():

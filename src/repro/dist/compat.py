@@ -1,58 +1,30 @@
-"""jax version compatibility for the distribution layer.
+"""Mesh and shard_map entry points of the distribution layer.
 
-The APIs the dist layer leans on drifted across jax releases:
-``shard_map`` moved from ``jax.experimental`` to the top level and its
-replication-check kwarg renamed ``check_rep`` → ``check_vma``;
-``jax.make_mesh`` grew an ``axis_types`` kwarg (with
-``jax.sharding.AxisType``).  Everything in-repo (and the subprocess
-probes in tests/benchmarks) goes through these wrappers so one tree runs
-on both API generations.
+Thin wrappers that fix the repository's conventions in one place:
+``shard_map`` is ``jax.shard_map`` with an optional ``check_vma``, and
+every mesh is built with ``AxisType.Auto`` axes (GSPMD picks layouts
+unless a program pins them).
 """
 
 from __future__ import annotations
 
-import inspect
-
 import jax
-
-try:                                    # jax >= 0.6: top-level export
-    from jax import shard_map as _shard_map
-except ImportError:                     # older jax: experimental module
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# The kwarg rename (check_rep → check_vma) happened independently of the
-# export move, so probe the signature rather than the import location.
-_CHECK_KW = ("check_vma" if "check_vma" in
-             inspect.signature(_shard_map).parameters else "check_rep")
+from jax.sharding import AxisType
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None):
-    """``jax.shard_map`` across API generations (``check_vma`` maps onto
-    ``check_rep`` for older jax)."""
-    kwargs = {} if check_vma is None else {_CHECK_KW: check_vma}
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kwargs)
-
-
-def auto_axis_types(n: int):
-    """``(AxisType.Auto,) * n`` where supported, else None."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return None
-    return (axis_type.Auto,) * n
+    """``jax.shard_map``; ``check_vma=None`` keeps jax's default."""
+    kwargs = {} if check_vma is None else {"check_vma": check_vma}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """``jax.make_mesh`` with auto axis types when the kwarg exists."""
+    """``jax.make_mesh`` with auto axis types."""
     kwargs = {} if devices is None else {"devices": devices}
-    types = auto_axis_types(len(tuple(axis_names)))
-    if types is not None:
-        try:
-            return jax.make_mesh(axis_shapes, axis_names,
-                                 axis_types=types, **kwargs)
-        except TypeError:
-            pass
-    return jax.make_mesh(axis_shapes, axis_names, **kwargs)
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(tuple(axis_names)),
+                         **kwargs)
 
 
 def make_client_mesh(num_clients: int, axis_name: str = "data"):

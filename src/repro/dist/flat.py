@@ -30,6 +30,11 @@ Pallas kernel streaming tiles through VMEM.
   (bf16/f16/f32 into f32 — params trees).  Wider or non-float leaves
   are rejected loudly rather than rounded silently.
 
+:func:`map_dtype_buffers` is the codec-free mixer's layout: one buffer
+per leaf dtype, each in that dtype, so a bf16 model's round holds a
+bf16 population.  The resident ``flat_io`` and error-feedback layouts of
+:class:`repro.runtime.SlotTrainLoop` still use one f32 buffer.
+
 Specs are pure shape/dtype metadata (hashable, built at trace time from
 tracers), so a jitted mixer rebuilds its spec deterministically per
 trace and zero-retrace behavior is untouched.
@@ -38,7 +43,7 @@ trace and zero-retrace behavior is untouched.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Tuple
+from typing import Any, Callable, Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -144,3 +149,20 @@ class FlatSpec:
             seg = jax.lax.slice_in_dim(row, off, off + size, axis=0)
             leaves.append(jnp.reshape(seg, shape).astype(dt))
         return self.treedef.unflatten(leaves)
+
+
+def map_dtype_buffers(tree, fn: Callable[[jnp.ndarray], jnp.ndarray]):
+    """Ravel the leaves of each dtype into one (B, N) buffer of that
+    dtype, apply ``fn`` (buffer -> same-shaped buffer) to each, and
+    unravel back into a tree shaped like ``tree``."""
+    leaves, treedef = jax.tree.flatten(tree)
+    groups: Dict[Any, List[int]] = {}
+    for i, leaf in enumerate(leaves):
+        groups.setdefault(jnp.dtype(leaf.dtype), []).append(i)
+    out = list(leaves)
+    for dt, idx in groups.items():
+        part = [leaves[i] for i in idx]
+        spec = FlatSpec.for_tree(part, dtype=dt)
+        for i, leaf in zip(idx, spec.unravel(fn(spec.ravel(part)))):
+            out[i] = leaf
+    return treedef.unflatten(out)

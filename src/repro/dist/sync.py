@@ -83,7 +83,7 @@ from ..kernels.weighted_mix import gather_mix, mix_accumulate
 from ..obs.events import get_telemetry
 from ..obs.profile import scope
 from ..wire.codec import WireCodec, get_codec
-from .flat import FlatSpec
+from .flat import FlatSpec, map_dtype_buffers
 
 #: Sync strategies understood by both mixer factories.
 SYNC_STRATEGIES = ("fedlay", "allreduce", "ring", "none")
@@ -604,8 +604,17 @@ def global_mixer(strategy: str,
                 return mix_flat_ef
 
             def mix_flat(params, *rest, **kw):
-                spec = FlatSpec.for_tree(params)
-                return spec.unravel(inner(spec.ravel(params), *rest, **kw))
+                if codec is not None:
+                    spec = FlatSpec.for_tree(params)
+                    return spec.unravel(inner(spec.ravel(params), *rest,
+                                              **kw))
+                # codec-free rounds mix each leaf dtype in a buffer of
+                # that dtype: the kernel accumulates in f32 and rounds
+                # once either way, so results are those of one f32
+                # buffer, while a bf16 model's round holds a bf16
+                # population (half the HBM) instead of an f32 one
+                return map_dtype_buffers(
+                    params, lambda buf: inner(buf, *rest, **kw))
             return mix_flat
 
         def mix(params):

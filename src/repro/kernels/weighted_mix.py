@@ -28,10 +28,15 @@ shapes the mixing paths produce (see :mod:`repro.dist.sync`):
   output row — HBM traffic 2·C·N regardless of the overlay degree, and
   no materialized receive temporaries at all.
 
-Grids are 1-D over N/BN lane-aligned tiles; K (≤ ~13: self + 2L
+Grids are 1-D over ceil(N/BN) lane-aligned tiles; K (≤ ~13: self + 2L
 neighbors) and C (clients per controller, ≤ a few dozen) ride whole in
-VMEM per tile.  The MXU is idle — these kernels live on the VPU — so
-tiles are sized for bandwidth, not matmul alignment.  ``interpret``
+VMEM per tile.  BN need not divide N: Pallas masks the part of the last
+tile that hangs past the array (columns are independent, so it never
+reaches an in-range output).  A lane-aligned buffer — every
+:class:`repro.dist.flat.FlatSpec` buffer — is therefore read and written
+in place; only a width that is not a lane multiple pays a padded copy.
+The MXU is idle — these kernels live on the VPU — so tiles are sized
+for bandwidth, not matmul alignment.  ``interpret``
 defaults to auto (:func:`repro.kernels.interpret.resolve_interpret`):
 compiled on TPU, interpreted (still traceable under jit/shard_map)
 everywhere else.
@@ -51,6 +56,8 @@ from .interpret import resolve_interpret
 
 #: TPU vector lane width — every block's minor dim must be a multiple.
 LANE = 128
+#: f32 sublane count — VMEM pads a tile's second-minor dim to it.
+SUBLANE = 8
 
 
 def aligned_block_n(n: int, block_n: int, lane: int = LANE) -> int:
@@ -69,13 +76,28 @@ def _default_block_n(n: int, rows: int, interp: bool) -> int:
     """Tile-width default shared by the mix entries.
 
     Tiling exists to fit VMEM, so it only applies to the compiled
-    kernel: a ~2 MB f32 tile budget per (rows, bn) operand
-    (bn ≈ 2^19 / rows elements).  Interpret mode has no VMEM — and its
-    grid loop copies operands per cell — so it runs the whole
+    kernel: a ~2 MB f32 tile budget per (rows, bn) operand as VMEM
+    holds it, rows rounded up to the 8-sublane tile
+    (bn ≈ 2^19 / rows_padded elements).  Interpret mode has no VMEM —
+    and its grid loop copies operands per cell — so it runs the whole
     (lane-padded) vector as one grid cell."""
     if interp:
         return max(LANE, n)
-    return max(LANE, (2 ** 19 // max(rows, 1)) // LANE * LANE)
+    rows_padded = -(-max(rows, 1) // SUBLANE) * SUBLANE
+    return max(LANE, (2 ** 19 // rows_padded) // LANE * LANE)
+
+
+def _pad_cols(x: jnp.ndarray, width: int) -> jnp.ndarray:
+    """Zero-pad the columns of a 2-D ``x`` to ``width`` (no copy when
+    there is nothing to pad)."""
+    pad = width - x.shape[1]
+    return jnp.pad(x, ((0, 0), (0, pad))) if pad else x
+
+
+def _lane_pad(x: jnp.ndarray) -> jnp.ndarray:
+    """Pad the columns to a lane multiple (a no-op for every FlatSpec
+    buffer, which is lane-aligned by construction)."""
+    return _pad_cols(x, -(-x.shape[1] // LANE) * LANE)
 
 
 def _mix_kernel(models_ref, weights_ref, out_ref):
@@ -113,15 +135,13 @@ def weighted_mix(models: jnp.ndarray, weights: jnp.ndarray, *,
         weights = jnp.where(total > 0, eff / jnp.where(total > 0, total, 1.0),
                             jnp.zeros_like(eff))
     bn = aligned_block_n(N, block_n)
-    pad = (-N) % bn
-    if pad:
-        models = jnp.pad(models, ((0, 0), (0, pad)))
+    models = _lane_pad(models)
     Np = models.shape[1]
     w2 = weights.reshape(K, 1).astype(jnp.float32)
 
     out = pl.pallas_call(
         _mix_kernel,
-        grid=(Np // bn,),
+        grid=(pl.cdiv(Np, bn),),
         in_specs=[
             pl.BlockSpec((K, bn), lambda i: (0, i)),
             pl.BlockSpec((K, 1), lambda i: (0, 0)),
@@ -130,7 +150,7 @@ def weighted_mix(models: jnp.ndarray, weights: jnp.ndarray, *,
         out_shape=jax.ShapeDtypeStruct((Np,), models.dtype),
         interpret=interp,
     )(models, w2)
-    return out[:N]
+    return out if Np == N else out[:N]
 
 
 def _accum_kernel(acc_ref, x_ref, w_ref, out_ref):
@@ -167,9 +187,9 @@ def mix_accumulate(acc: Optional[jnp.ndarray], x: jnp.ndarray,
     if block_n is None:
         block_n = _default_block_n(N, B, interp)
     bn = aligned_block_n(N, block_n)
-    pad = (-N) % bn
-    xs = jnp.pad(x, ((0, 0), (0, pad))) if pad else x
+    xs = _lane_pad(x)
     Np = xs.shape[1]
+    grid = (pl.cdiv(Np, bn),)
     w2 = w.reshape(B, 1).astype(jnp.float32)
     row_spec = pl.BlockSpec((B, bn), lambda i: (0, i))
     w_spec = pl.BlockSpec((B, 1), lambda i: (0, 0))
@@ -177,24 +197,24 @@ def mix_accumulate(acc: Optional[jnp.ndarray], x: jnp.ndarray,
         with scope("kernels.mix_accumulate.init"):
             out = pl.pallas_call(
                 _scale_kernel,
-                grid=(Np // bn,),
+                grid=grid,
                 in_specs=[row_spec, w_spec],
                 out_specs=row_spec,
                 out_shape=jax.ShapeDtypeStruct((B, Np), x.dtype),
                 interpret=interp,
             )(xs, w2)
-        return out[:, :N]
-    accs = jnp.pad(acc, ((0, 0), (0, pad))) if pad else acc
+        return out if Np == N else out[:, :N]
+    accs = _lane_pad(acc)
     with scope("kernels.mix_accumulate"):
         out = pl.pallas_call(
             _accum_kernel,
-            grid=(Np // bn,),
+            grid=grid,
             in_specs=[row_spec, row_spec, w_spec],
             out_specs=row_spec,
             out_shape=jax.ShapeDtypeStruct((B, Np), acc.dtype),
             interpret=interp,
         )(accs, xs, w2)
-    return out[:, :N]
+    return out if Np == N else out[:, :N]
 
 
 def round_matrix(C: int, srcs, weights: jnp.ndarray) -> jnp.ndarray:
@@ -221,10 +241,13 @@ def round_matrix(C: int, srcs, weights: jnp.ndarray) -> jnp.ndarray:
 def _gather_mix_kernel(W_ref, models_ref, out_ref):
     # W: (C, C) round-mixing matrix (stationary across tiles);
     # models: (C, BN) — the whole population's column tile, read once
-    # and serving every output row via one MXU matmul.
+    # and serving every output row via one MXU matmul.  HIGHEST: at the
+    # default precision the MXU rounds f32 operands to bf16, which cost
+    # f32 models ~3 significant digits per round on a v5e.
     out_ref[...] = jnp.dot(
         W_ref[...], models_ref[...].astype(jnp.float32),
-        preferred_element_type=jnp.float32).astype(out_ref.dtype)
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST).astype(out_ref.dtype)
 
 
 def gather_mix(buf: jnp.ndarray, srcs, weights: jnp.ndarray,
@@ -277,20 +300,23 @@ def gather_mix(buf: jnp.ndarray, srcs, weights: jnp.ndarray,
         block_n = _default_block_n(N, C, interp)
     W = round_matrix(C, srcs, weights)
     bn = aligned_block_n(N, block_n)
-    pad = (-N) % bn
-    bufs = jnp.pad(buf, ((0, 0), (0, pad))) if pad else buf
+    bufs = _lane_pad(buf)
     Np = bufs.shape[1]
 
     with scope("kernels.gather_mix"):
         out = pl.pallas_call(
             _gather_mix_kernel,
-            grid=(Np // bn,),
+            grid=(pl.cdiv(Np, bn),),
             in_specs=[
                 pl.BlockSpec((C, C), lambda i: (0, 0)),
                 pl.BlockSpec((C, bn), lambda i: (0, i)),
             ],
             out_specs=pl.BlockSpec((C, bn), lambda i: (0, i)),
             out_shape=jax.ShapeDtypeStruct((C, Np), buf.dtype),
+            # in place: out tile i depends only on in tile i, so a
+            # buffer that dies here (a round's raveled temp) is reused
+            # instead of holding a second (C, N) population in HBM
+            input_output_aliases={1: 0},
             interpret=interp,
         )(W, bufs)
-    return out[:, :N]
+    return out if Np == N else out[:, :N]

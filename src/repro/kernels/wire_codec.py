@@ -42,8 +42,9 @@ stored scale that underflows to 0 quantizes through a safe scale of 1
 
 Grids are 1-D over lane-aligned column tiles sized by the shared ~2 MB
 budget of :func:`repro.kernels.weighted_mix._default_block_n`, rounded
-to a multiple of lcm(block, LANE) so per-tile scale columns stay whole;
-interpret mode (the CPU test mesh) runs a single cell.  The compiled
+to a multiple of block·LANE so per-tile scale columns stay whole and
+lane-aligned; the last tile may overhang the width (masked by Pallas).
+Interpret mode (the CPU test mesh) runs a single cell.  The compiled
 TPU path wants ``block`` a multiple of :data:`~repro.kernels.weighted_mix.LANE`
 (the int8 min tile is (32, 128) — see the accelerator guide);
 odd block sizes still work everywhere interpret mode runs.
@@ -51,7 +52,6 @@ odd block sizes still work everywhere interpret mode runs.
 
 from __future__ import annotations
 
-import math
 from typing import Optional, Tuple
 
 import jax
@@ -60,7 +60,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from .interpret import resolve_interpret
-from .weighted_mix import LANE, _default_block_n, round_matrix
+from .weighted_mix import LANE, _default_block_n, _pad_cols, round_matrix
 
 
 def padded_width(n: int, block: int) -> int:
@@ -71,25 +71,19 @@ def padded_width(n: int, block: int) -> int:
     return -(-n // block) * block
 
 
-def _pad_cols(x: jnp.ndarray, width: int) -> jnp.ndarray:
-    pad = width - x.shape[1]
-    return jnp.pad(x, ((0, 0), (0, pad))) if pad else x
-
-
 def _tile_width(np_: int, rows: int, block: int, interp: bool) -> int:
     """Columns per grid cell: the whole (block-padded) width in
-    interpret mode; else the largest power-of-two multiple of
-    lcm(block, LANE) dividing ``np_`` within the ~2 MB budget."""
-    if interp:
+    interpret mode or when it fits one ``block·LANE`` unit; else the
+    largest multiple of ``block·LANE`` within the ~2 MB budget, so each
+    tile's (rows, bn/block) scale block keeps a lane-multiple minor dim.
+    The grid is ``cdiv(np_, bn)``: Pallas masks the overhang of the last
+    tile, and quantization blocks never straddle a tile, so the
+    overhang never reaches an in-range output."""
+    unit = block * LANE
+    if interp or np_ <= unit:
         return np_
-    unit = block * LANE // math.gcd(block, LANE)
-    if np_ % unit:
-        return np_                      # odd geometry: single cell
     budget = _default_block_n(np_, rows, False)
-    bn = unit
-    while bn * 2 <= min(budget, np_) and np_ % (bn * 2) == 0:
-        bn *= 2
-    return bn
+    return max(unit, budget // unit * unit)
 
 
 def quantize_block(x: jnp.ndarray, *, block: int = 128, levels: int = 127,
@@ -135,7 +129,7 @@ def quantize_block(x: jnp.ndarray, *, block: int = 128, levels: int = 127,
         out_shape.append(jax.ShapeDtypeStruct((B, Np), jnp.float32))
         out_specs.append(row_spec)
     out = pl.pallas_call(
-        kernel, grid=(Np // bn,), in_specs=[row_spec],
+        kernel, grid=(pl.cdiv(Np, bn),), in_specs=[row_spec],
         out_specs=out_specs, out_shape=out_shape, interpret=interp)(xs)
     if with_residual:
         return out[0], out[1], out[2][:, :N]
@@ -164,7 +158,7 @@ def dequantize_block(q: jnp.ndarray, scales: jnp.ndarray, *,
         out_ref[...] = deq.reshape(B, bn)
 
     out = pl.pallas_call(
-        kernel, grid=(Nq // bn,),
+        kernel, grid=(pl.cdiv(Nq, bn),),
         in_specs=[pl.BlockSpec((B, bn), lambda i: (0, i)),
                   pl.BlockSpec((B, nb), lambda i: (0, i))],
         out_specs=pl.BlockSpec((B, bn), lambda i: (0, i)),
@@ -216,14 +210,14 @@ def dequant_accumulate(acc: Optional[jnp.ndarray], q: jnp.ndarray,
     w_spec = pl.BlockSpec((B, 1), lambda i: (0, 0))
     if acc is None:
         out = pl.pallas_call(
-            kernel, grid=(Nq // bn,),
+            kernel, grid=(pl.cdiv(Nq, bn),),
             in_specs=[row_spec, s_spec, w_spec], out_specs=row_spec,
             out_shape=jax.ShapeDtypeStruct((B, Nq), jnp.float32),
             interpret=interp)(q, scales, w2)
         return out
     accs = _pad_cols(acc, Nq)
     out = pl.pallas_call(
-        kernel, grid=(Nq // bn,),
+        kernel, grid=(pl.cdiv(Nq, bn),),
         in_specs=[row_spec, row_spec, s_spec, w_spec], out_specs=row_spec,
         out_shape=jax.ShapeDtypeStruct((B, Nq), acc.dtype),
         interpret=interp)(accs, q, scales, w2)
@@ -260,10 +254,11 @@ def gather_mix_int8(q: jnp.ndarray, scales: jnp.ndarray, srcs,
         deq = q_ref[...].astype(jnp.float32).reshape(C, nb, block) \
             * s[:, :, None]
         out_ref[...] = jnp.dot(W_ref[...], deq.reshape(C, bn),
-                               preferred_element_type=jnp.float32)
+                               preferred_element_type=jnp.float32,
+                               precision=jax.lax.Precision.HIGHEST)
 
     out = pl.pallas_call(
-        kernel, grid=(Nq // bn,),
+        kernel, grid=(pl.cdiv(Nq, bn),),
         in_specs=[pl.BlockSpec((C, C), lambda i: (0, 0)),
                   pl.BlockSpec((C, bn), lambda i: (0, i)),
                   pl.BlockSpec((C, nb), lambda i: (0, i))],

@@ -33,7 +33,7 @@ _DTYPE_BYTES = {
 }
 
 _OP_RE = re.compile(
-    r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*(\([^=]*\)|[\w\[\],{}\s/]+?)\s+"
+    r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*(\([^=]*\)|[\w\[\],{}\s/:()]+?)\s+"
     r"(all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute)"
     r"(?:-start|-done)?\(",
     re.M)

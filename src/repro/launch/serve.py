@@ -36,10 +36,11 @@ import numpy as np
 
 from ..configs import REGISTRY, reduce_for_smoke
 from ..models.model import decode_step, init_cache, init_params, prefill
+from .compile_cache import enable_compile_cache
 from .train import tiny_lm
 
 
-def _check_tokens(gen_tokens: jnp.ndarray, vocab: int) -> None:
+def check_tokens(gen_tokens: jnp.ndarray, vocab: int) -> None:
     """Output-validity gate.  A real ``raise`` — the old ``assert``
     vanished under ``python -O``."""
     if bool(jnp.any(gen_tokens < 0)) or bool(jnp.any(gen_tokens >= vocab)):
@@ -85,7 +86,7 @@ def run_batch(cfg, params, args, rng) -> int:
           f"{prefill_s:.3f}s; decode: "
           f"{B * args.gen / max(gen_s, 1e-9):.1f} tok/s")
     print("sample:", np.asarray(gen_tokens[0, :16]).tolist())
-    _check_tokens(gen_tokens, cfg.vocab_size)
+    check_tokens(gen_tokens, cfg.vocab_size)
     return 0
 
 
@@ -108,7 +109,7 @@ def run_slots(cfg, params, args, rng) -> int:
     lat = sorted(r.latency_s for r in done)
     toks = sum(len(r.tokens) for r in done)
     for r in done:
-        _check_tokens(jnp.asarray(r.tokens), cfg.vocab_size)
+        check_tokens(jnp.asarray(r.tokens), cfg.vocab_size)
     print(f"{args.policy}: {len(done)} requests in {wall:.3f}s "
           f"({len(done) / wall:.1f} req/s, {toks / wall:.1f} tok/s), "
           f"p50 {lat[len(lat) // 2] * 1e3:.1f}ms "
@@ -135,6 +136,7 @@ def main() -> int:
                     default="continuous")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.arch == "tiny":
         cfg = tiny_lm()

@@ -37,6 +37,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core.mixing import build_permute_schedule
+from .compile_cache import enable_compile_cache
 from ..data.tokens import TokenStream
 from ..dist.compat import make_client_mesh, shard_map
 from ..dist.sync import make_mixer
@@ -254,7 +255,9 @@ def run(args) -> Dict:
 
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("--clients", type=int, default=len(jax.devices()))
+    ap.add_argument("--clients", type=int, default=None,
+                    help="total clients (default: --clients-per-device "
+                         "× devices)")
     ap.add_argument("--clients-per-device", type=int, default=1,
                     help="G local clients per mesh device "
                          "(total clients = G × devices)")
@@ -295,6 +298,9 @@ def main() -> int:
                     help="capture a jax.profiler trace of the run into "
                          "PATH (view with TensorBoard / Perfetto)")
     args = ap.parse_args()
+    if args.clients is None:
+        args.clients = args.clients_per_device * jax.device_count()
+    enable_compile_cache()
     res = run(args)
     print(f"loss {res['first_loss']:.4f} -> {res['final_loss']:.4f}")
     return 0

@@ -84,6 +84,30 @@ def stack_rows(trees):
     return jax.tree.map(lambda *ls: jax.numpy.stack(ls), *trees)
 
 
+def build_rows(make_row: Callable[[int], object], capacity: int,
+               sharding=None):
+    """Stack ``make_row(i)`` for every row ``i < capacity``.  With
+    ``sharding`` (whose first dim splits the rows over devices) each
+    device makes and stacks only the rows it holds, under
+    ``jax.default_device``: the population is never gathered on one
+    device, where published-width models do not fit together."""
+    import jax
+    if sharding is None:
+        return stack_rows([make_row(i) for i in range(capacity)])
+    made, parts = {}, []
+    for dev, (rows, *_) in sharding.devices_indices_map(
+            (capacity,)).items():
+        lo, hi, _ = rows.indices(capacity)
+        if (lo, hi) not in made:        # rows replicated on other axes
+            with jax.default_device(dev):
+                made[lo, hi] = stack_rows([jax.device_put(make_row(i), dev)
+                                           for i in range(lo, hi)])
+        parts.append(jax.device_put(made[lo, hi], dev))
+    return jax.tree.map(
+        lambda *ls: jax.make_array_from_single_device_arrays(
+            (capacity,) + ls[0].shape[1:], sharding, list(ls)), *parts)
+
+
 def tree_row(tree, i: int):
     """Row ``i`` of every leaf (one client's unstacked state)."""
     import jax
@@ -235,23 +259,31 @@ class SlotTrainLoop:
         self._bytes_cache: Dict[tuple, tuple] = {}
 
         # capacity-stacked state: live slots get their node's init, dead
-        # slots zeros (their rows are masked and mixed as self-loops)
-        template = None
-        rows = []
-        for slot in range(self.capacity):
-            node = controller.slots.node_at(slot)
-            if node is not None:
-                row = make_params(node)
-                template = template if template is not None else row
-                rows.append(row)
-            else:
-                rows.append(None)
-        if template is None:
+        # slots zeros (their rows are masked and mixed as self-loops);
+        # params and optimizer state are born sharded over the mesh
+        nodes = [controller.slots.node_at(s) for s in range(self.capacity)]
+        first = next((u for u in nodes if u is not None), None)
+        if first is None:
             raise ValueError("controller has no live nodes")
-        dead = jax.tree.map(lambda l: jax.numpy.zeros_like(l), template)
-        rows = [r if r is not None else dead for r in rows]
-        stacked = self._stack(rows)
-        self.opt_state = self._shard_rows(jax.vmap(optimizer.init)(stacked))
+        if None in nodes:
+            # dead rows take a live row's shapes (that row is dropped
+            # before the population is built)
+            template = jax.tree.map(
+                lambda l: jax.ShapeDtypeStruct(np.shape(l), l.dtype),
+                make_params(first))
+
+        def make_row(slot):
+            if nodes[slot] is not None:
+                return make_params(nodes[slot])
+            return jax.tree.map(
+                lambda l: jax.numpy.zeros(l.shape, l.dtype), template)
+        stacked = build_rows(make_row, self.capacity, None if mesh is None
+                             else self._row_sharding(np.empty(self.capacity)))
+        init = jax.vmap(optimizer.init)
+        if mesh is not None:
+            init = jax.jit(init, out_shardings=jax.tree.map(
+                self._row_sharding, jax.eval_shape(init, stacked)))
+        self.opt_state = init(stacked)
 
         self.codec = controller.codec
         self.ef = self.codec is not None and self.codec.error_feedback
@@ -294,24 +326,24 @@ class SlotTrainLoop:
         self.records: List[SlotStepRecord] = []
 
     # ---- state surgery ---------------------------------------------------
-    def _stack(self, trees):
-        return stack_rows(trees)
-
     def _shard_rows(self, tree):
         """Pin capacity-stacked leaves to the canonical row sharding
         over ``mesh``'s client axis (no-op without a mesh; leaves
         without the leading capacity dim are replicated)."""
         if self.mesh is None:
             return tree
-        from jax.sharding import NamedSharding, PartitionSpec as P
+        return self._jax.tree.map(
+            lambda l: self._jax.device_put(l, self._row_sharding(l)), tree)
 
-        def put(l):
-            if getattr(l, "ndim", 0) >= 1 and l.shape[0] == self.capacity:
-                spec = P(self.client_axis, *([None] * (l.ndim - 1)))
-            else:
-                spec = P()
-            return self._jax.device_put(l, NamedSharding(self.mesh, spec))
-        return self._jax.tree.map(put, tree)
+    def _row_sharding(self, leaf):
+        """Capacity rows over the client axis; anything else
+        replicated."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        if getattr(leaf, "ndim", 0) >= 1 and leaf.shape[0] == self.capacity:
+            spec = P(self.client_axis, *([None] * (leaf.ndim - 1)))
+        else:
+            spec = P()
+        return NamedSharding(self.mesh, spec)
 
     def _row(self, tree, i: int):
         return tree_row(tree, i)

@@ -155,8 +155,11 @@ class ServeLoop:
         self.policy = policy
 
         self.slots = SlotMap(capacity)
+        # the KV cache holds K/V in the params' own dtype (a bf16 model
+        # keeps a bf16 cache, half the HBM of an f32 one)
+        cache_dtype = jnp.result_type(*jax.tree.leaves(params))
         self.cache = init_cache(cfg, params, capacity, cache_len,
-                                per_slot_pos=True)
+                                dtype=cache_dtype, per_slot_pos=True)
         self._tok = jnp.zeros((capacity, 1), jnp.int32)
         self._pos_host = np.full((capacity,), -1, np.int64)
         self.pending: Deque[Request] = deque()
@@ -168,7 +171,8 @@ class ServeLoop:
         cfg_ = cfg
 
         def _prefill_fn(params, tokens, lengths):
-            c0 = init_cache(cfg_, params, 1, cache_len, per_slot_pos=True)
+            c0 = init_cache(cfg_, params, 1, cache_len, dtype=cache_dtype,
+                            per_slot_pos=True)
             logits, c1 = prefill(cfg_, params, c0, tokens, lengths=lengths)
             tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
             return tok, c1
@@ -197,10 +201,15 @@ class ServeLoop:
                 cache["pos"], jnp.full((1,), -1, cache["pos"].dtype), (slot,))
             return new
 
+        # the cache is donated: every step rewrites it in place, so one
+        # copy exists however many steps are queued ahead of the device
         self._prefill_j, self._tc_prefill = counting_jit(_prefill_fn)
-        self._insert_j, self._tc_insert = counting_jit(_insert_fn)
-        self._decode_j, self._tc_decode = counting_jit(_decode_fn)
-        self._retire_j, self._tc_retire = counting_jit(_retire_fn)
+        self._insert_j, self._tc_insert = counting_jit(_insert_fn,
+                                                       donate_argnums=0)
+        self._decode_j, self._tc_decode = counting_jit(_decode_fn,
+                                                       donate_argnums=1)
+        self._retire_j, self._tc_retire = counting_jit(_retire_fn,
+                                                       donate_argnums=0)
 
     # ---- request intake --------------------------------------------------
     def submit(self, prompt: Sequence[int], max_new: int = 16,

@@ -1,6 +1,5 @@
 import os
 import sys
-import types
 
 # Tier-1 runs on a forced 8-device CPU mesh so shard_map mixer paths
 # (repro.dist.sync) execute as genuine multi-device programs instead of
@@ -20,97 +19,12 @@ if ("xla_force_host_platform_device_count" not in _flags
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-try:
-    from hypothesis import settings
-except ModuleNotFoundError:
-    # Offline container without hypothesis: install a shim so the
-    # property-test modules still collect; every @given test is skipped.
-    import pytest
-
-    def _skip_given(*_args, **_kwargs):
-        def deco(fn):
-            return pytest.mark.skip(
-                reason="hypothesis not installed")(fn)
-        return deco
-
-    class _NoopSettings:
-        """No-op stand-in for hypothesis.settings (decorator + profiles)."""
-
-        def __init__(self, *args, **kwargs):
-            pass
-
-        def __call__(self, fn):
-            return fn
-
-        @staticmethod
-        def register_profile(*args, **kwargs):
-            pass
-
-        @staticmethod
-        def load_profile(*args, **kwargs):
-            pass
-
-    class _DummyStrategy:
-        """Inert strategy stand-in: supports the combinator surface
-        (map/filter/flatmap/|) so module-level strategy expressions in
-        property-test files evaluate under collection."""
-
-        def map(self, *_a, **_k):
-            return self
-
-        def filter(self, *_a, **_k):
-            return self
-
-        def flatmap(self, *_a, **_k):
-            return self
-
-        def example(self):
-            return None
-
-        def __or__(self, _other):
-            return self
-
-        def __call__(self, *_a, **_k):
-            return self
-
-    def _strategy(*_args, **_kwargs):
-        return _DummyStrategy()
-
-    def _composite(fn):
-        # @st.composite functions must stay callable (they are invoked at
-        # module level to build strategies); the result is inert.
-        def build(*_a, **_k):
-            return _DummyStrategy()
-        return build
-
-    _st = types.ModuleType("hypothesis.strategies")
-    for _name in ("integers", "floats", "lists", "tuples", "sampled_from",
-                  "booleans", "just", "text", "one_of", "none", "data",
-                  "dictionaries", "sets", "binary", "characters",
-                  "permutations"):
-        setattr(_st, _name, _strategy)
-    _st.composite = _composite
-    _st.SearchStrategy = _DummyStrategy
-    # any strategy name we did not anticipate still resolves (PEP 562)
-    _st.__getattr__ = lambda _name: _strategy
-
-    _hyp = types.ModuleType("hypothesis")
-    _hyp.given = _skip_given
-    _hyp.settings = _NoopSettings
-    _hyp.strategies = _st
-    _hyp.assume = lambda *a, **k: True
-    _hyp.example = _skip_given
-    _hyp.HealthCheck = types.SimpleNamespace(all=staticmethod(lambda: []))
-    # cover both import spellings: ``from hypothesis import strategies``
-    # AND ``import hypothesis.strategies as st`` in property-test modules
-    sys.modules["hypothesis"] = _hyp
-    sys.modules["hypothesis.strategies"] = _st
-    settings = _NoopSettings
+from hypothesis import settings  # noqa: E402
 
 settings.register_profile("ci", deadline=None, max_examples=25)
 settings.load_profile("ci")
 
-import pytest  # noqa: E402  (after the hypothesis shim)
+import pytest  # noqa: E402
 
 
 def pytest_configure(config):

@@ -238,6 +238,30 @@ def test_fused_global_mixer_preserves_bf16_leaves():
                                atol=2e-2)
 
 
+def test_fused_global_mixer_mixed_dtypes_match_one_f32_buffer():
+    """Codec-free flat rounds mix each leaf dtype in its own buffer; the
+    kernel accumulates in f32 and rounds once, so the result is exactly
+    that of one f32 buffer over the whole tree."""
+    C = 4
+    sched = build_permute_schedule(C, 2, salt="mixed")
+    rng = np.random.default_rng(1)
+    tree = {"a": jnp.asarray(rng.normal(size=(C, 3, 50)), jnp.bfloat16),
+            "b": jnp.asarray(rng.normal(size=(C, 7)), jnp.float32),
+            "c": jnp.asarray(rng.normal(size=(C, 130)), jnp.bfloat16)}
+    mask = jnp.asarray([1.0, 1.0, 0.0, 1.0])
+    out = jax.jit(global_mixer("fedlay", sched, masked=True,
+                               fuse="flat"))(tree, mask)
+    assert jax.tree.map(lambda l: l.dtype, out) == \
+        jax.tree.map(lambda l: l.dtype, tree)
+    spec = FlatSpec.for_tree(tree)              # one f32 buffer
+    one_buffer = global_mixer("fedlay", sched, masked=True, fuse="flat",
+                              flat_io=True)
+    ref = spec.unravel(jax.jit(one_buffer)(spec.ravel(tree), mask))
+    for k in tree:
+        np.testing.assert_array_equal(np.asarray(out[k], np.float32),
+                                      np.asarray(ref[k], np.float32))
+
+
 @pytest.mark.multi_device
 @pytest.mark.parametrize("strategy", ("fedlay", "ring"))
 def test_fused_make_mixer_equals_unfused(strategy, multi_device):

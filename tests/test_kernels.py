@@ -120,18 +120,21 @@ def test_mix_accumulate_incremental_equals_stacked():
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_gather_mix_equals_dense_product(dtype):
+@pytest.mark.parametrize("N,bn", [(1000, 256), (1280, 512)])
+def test_gather_mix_equals_dense_product(dtype, N, bn):
     """The whole-round kernel: static source rows + runtime weights ≡
-    the dense W·X it encodes."""
+    the dense W·X it encodes — also when the last tile overhangs a
+    lane-aligned width (1280 = 2.5 tiles of 512, no padded copy)."""
     from repro.kernels.ref import gather_mix_ref
     from repro.kernels.weighted_mix import gather_mix
-    C, N, K1 = 8, 1000, 5
+    C, K1 = 8, 5
     rng = np.random.default_rng(3)
     buf = jnp.asarray(rng.normal(size=(C, N)), dtype)
     srcs = rng.integers(0, C, size=(C, K1))
     srcs[:, 0] = np.arange(C)                   # self column
     w = jnp.asarray(rng.random((C, K1)).astype(np.float32))
-    out = gather_mix(buf, srcs, w, block_n=256, interpret=True)
+    out = gather_mix(buf, srcs, w, block_n=bn, interpret=True)
+    assert out.shape == (C, N)
     assert out.dtype == buf.dtype
     ref = gather_mix_ref(buf, srcs, w)
     np.testing.assert_allclose(np.asarray(out, np.float32),

@@ -4,7 +4,7 @@ random churn schedules."""
 
 import numpy as np
 import pytest
-from hypothesis import given, settings, strategies as st
+from hypothesis import example, given, settings, strategies as st
 
 from repro.core.coords import NodeAddress, circular_distance, coordinates
 from repro.core.ndmp import Simulator
@@ -82,6 +82,9 @@ def test_mass_concurrent_failure():
                           st.integers(0, 10_000)),
                 min_size=1, max_size=12),
        st.integers(0, 5))
+# a joiner bootstrapping through a node that has not joined yet
+@example([("join", 0), ("join", 0), ("fail", 0), ("fail", 0),
+          ("join", 7078)], 0)
 def test_random_churn_schedule_converges(events, seed):
     """Property: any interleaving of joins/leaves/failures converges back
     to a correct FedLay (the paper's core resilience claim)."""

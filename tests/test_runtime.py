@@ -536,6 +536,39 @@ def test_grouped_slot_loop_capacity_2x_devices_zero_retrace(multi_device):
     assert loop.params["w"].sharding == NamedSharding(mesh, P("data", None))
 
 
+@pytest.mark.multi_device
+def test_slot_loop_makes_each_row_on_its_own_device(multi_device):
+    """With a mesh, each client's row is made on the device that holds
+    it and stacked there: no device ever holds the whole population."""
+    from repro.dist.compat import make_client_mesh
+    from repro.optim.optimizers import sgd
+
+    mesh = make_client_mesh(8, "data")
+    ctl = OverlayController(make_sim(n=12), capacity=16,
+                            clients_per_device=2)
+    made_on = {}
+
+    def make_params(u):
+        row = _make_params(u)
+        made_on[u] = row["w"].devices()
+        return row
+    loop = SlotTrainLoop(
+        ctl, local_step=masked_local_step(_base_step()),
+        make_params=make_params, optimizer=sgd(0.0),
+        make_batch=_make_batch, mesh=mesh)
+    w = loop.params["w"]
+    holder = {i: shard.device for shard in w.addressable_shards
+              for i in range(16)[shard.index[0]]}
+    for slot in range(16):
+        u = ctl.slots.node_at(slot)
+        if u is None:                            # dead slot: zeros
+            np.testing.assert_array_equal(np.asarray(w[slot]), 0.0)
+        else:
+            assert made_on[u] == {holder[slot]}
+            np.testing.assert_array_equal(np.asarray(w[slot]),
+                                          _make_params(u)["w"])
+
+
 def test_grouped_slot_loop_rejects_mismatched_mesh():
     from repro.dist.compat import make_client_mesh
     from repro.optim.optimizers import sgd

@@ -9,7 +9,7 @@ skip when hypothesis is not installed)."""
 
 import numpy as np
 import pytest
-from hypothesis import given, settings, strategies as st
+from hypothesis import example, given, settings, strategies as st
 
 from repro.core.coords import coordinates, coordinates_batch
 from repro.core.mep import ClientProfile
@@ -188,6 +188,8 @@ def test_large_population_batch_churn_converges():
                           st.integers(0, 10_000)),
                 min_size=1, max_size=10),
        st.integers(0, 3))
+# a joiner whose only contact fails in the same instant
+@example([("join", 0), ("fail", 0)], 0)
 def test_fuzz_batched_churn_parity(events, seed):
     """Property: the object engine applies events one by one, the
     vectorized engine in per-kind batches — same converged network."""

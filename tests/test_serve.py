@@ -247,6 +247,34 @@ def _loop(policy="continuous", capacity=3):
                      prompt_len=8, policy=policy)
 
 
+def test_serve_cache_takes_the_params_dtype():
+    """A bf16 model keeps a bf16 KV cache (half the HBM of f32), and
+    serves tokens inside the vocabulary from it."""
+    params = init_params(CFG, jax.random.PRNGKey(0), dtype=jnp.bfloat16)
+    loop = ServeLoop(CFG, params, capacity=2, cache_len=24, prompt_len=8)
+    kv = [l for l in jax.tree.leaves(loop.cache)
+          if jnp.issubdtype(l.dtype, jnp.floating)]
+    assert kv and all(l.dtype == jnp.bfloat16 for l in kv)
+    req = loop.submit(np.arange(1, 6), max_new=3)
+    loop.run()
+    assert len(req.tokens) == 3
+    assert all(0 <= t < CFG.vocab_size for t in req.tokens)
+
+
+def test_serve_rewrites_the_cache_in_place():
+    """Admission, decode and retirement donate the KV cache: each step's
+    input cache is consumed, so one copy of it is ever live."""
+    loop = _loop(capacity=2)
+    req = loop.submit(np.arange(1, 6), max_new=3)
+    ticks = 0
+    while not req.done:
+        before = jax.tree.leaves(loop.cache)
+        loop.tick()
+        ticks += 1
+        assert all(l.is_deleted() for l in before)
+    assert ticks == 2 and len(req.tokens) == 3
+
+
 def test_serve_churn_zero_retraces():
     """Request churn across >= 3 distinct occupancy counts compiles
     exactly one trace per step function — 0 retraces after warmup."""

@@ -129,6 +129,22 @@ FAKE_HLO = """
 """
 
 
+TPU_HLO = """
+  %all-gather.1 = bf16[4,1,1024]{2,1,0:T(2,128)(2,1)} all-gather(%b), channel_id=8, replica_groups=[1,4]<=[4], dimensions={0}
+  %all-reduce = f32[4]{0:T(128)S(1)} all-reduce(%d), channel_id=40, replica_groups=[1,4]<=[4], to_apply=%add
+  %cp.1 = (bf16[8,128]{1,0:T(8,128)(2,1)}, bf16[8,128]{1,0:T(8,128)(2,1)}) collective-permute-start(%w), source_target_pairs={{0,1},{1,0}}
+"""
+
+
+def test_collective_stats_parse_tpu_layouts():
+    """Compiled TPU HLO carries tiled layouts (``{1,0:T(8,128)}``) in
+    every shape; the parser still finds each collective once."""
+    st = collective_stats(TPU_HLO)
+    assert st.counts == {"all-gather": 1, "all-reduce": 1,
+                         "collective-permute": 1}
+    assert st.result_bytes["all-gather"] == 4 * 1024 * 2
+
+
 def test_collective_stats_parse():
     st = collective_stats(FAKE_HLO)
     assert st.counts == {"all-gather": 1, "all-reduce": 1,
