@@ -1,0 +1,2 @@
+"""Per-layer metric readers, one module per metric, named as in
+``BENCHMARK.json``'s ``per_layer``."""
