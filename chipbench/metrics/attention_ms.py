@@ -1,0 +1,11 @@
+"""Device time per round of blockwise attention, forward, recomputed
+and backward: the operations under the named scope
+``model.attention``, averaged over the cell's chips."""
+
+from chipbench import scopes
+
+
+def read(ctx):
+    return scopes.per_device_ms(
+        ctx, lambda ops, dev, lo, hi: scopes.scoped_ns(
+            ops, ("model.attention",), lo, hi))

@@ -20,6 +20,7 @@ from ..dist.sharding import (batch_spec, cache_specs, dfl_client_count,
 from ..dist.sync import SYNC_STRATEGIES, global_mixer, ring_schedule
 from ..models import decode_step, init_cache, init_params, train_loss
 from ..models.config import ArchConfig, InputShape
+from ..obs.profile import scope
 from ..optim.optimizers import (AdamWState, Optimizer, apply_updates,
                                 clip_by_global_norm)
 
@@ -320,13 +321,19 @@ def dfl_train_bundle(cfg: ArchConfig, shape: InputShape, mesh: Mesh,
     def per_client_loss(p, b):
         return train_loss(cfg, p, b, remat=remat, act_spec=act)
 
+    # the named scopes label the step's ops on the profiler's device
+    # planes (the benchmark's ``optimizer_ms`` and ``fwd_bwd_ms`` read
+    # them)
     def local_updates(params, opt_state, batch):
-        loss, grads = jax.vmap(jax.value_and_grad(per_client_loss))(
-            params, batch)
-        grads, _ = jax.vmap(lambda g: clip_by_global_norm(g, 1.0))(grads)
-        updates, opt_state = jax.vmap(optimizer.update)(grads, opt_state,
-                                                        params)
-        params = jax.vmap(apply_updates)(params, updates)
+        with scope("step.fwd_bwd"):
+            loss, grads = jax.vmap(jax.value_and_grad(per_client_loss))(
+                params, batch)
+        with scope("step.optimizer"):
+            grads, _ = jax.vmap(lambda g: clip_by_global_norm(g, 1.0))(
+                grads)
+            updates, opt_state = jax.vmap(optimizer.update)(
+                grads, opt_state, params)
+            params = jax.vmap(apply_updates)(params, updates)
         return params, opt_state, loss
 
     r_spec = r_shape = None
@@ -342,8 +349,9 @@ def dfl_train_bundle(cfg: ArchConfig, shape: InputShape, mesh: Mesh,
         def masked_local(params, opt_state, batch, mask):
             new_params, new_opt, loss = local_updates(params, opt_state,
                                                       batch)
-            params = masked_where(mask, new_params, params)
-            opt_state = masked_where(mask, new_opt, opt_state)
+            with scope("step.optimizer"):
+                params = masked_where(mask, new_params, params)
+                opt_state = masked_where(mask, new_opt, opt_state)
             return params, opt_state, {"loss": masked_mean(loss, mask),
                                        "num_alive": jnp.sum(mask)}
 

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.profile import scope
 from .layers import apply_rope, dense_init, matmul, rmsnorm, rmsnorm_init
 
 NEG_INF = -1e30
@@ -143,12 +144,14 @@ def blockwise_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                         chunk: Optional[int] = None,
                         causal: bool = True) -> jnp.ndarray:
     """Flash-attention memory behavior: never save the O(S·chunk) score
-    blocks for backward — recompute the blockwise pass from (q, k, v)."""
+    blocks for backward — recompute the blockwise pass from (q, k, v).
+    Ops under the named scope ``model.attention``."""
     import functools
     inner = functools.partial(_blockwise_attention, window=window,
                               chunk=chunk, causal=causal)
     inner = jax.checkpoint(inner, policy=jax.checkpoint_policies.nothing_saveable)
-    return inner(q, k, v)
+    with scope("model.attention"):
+        return inner(q, k, v)
 
 
 def gqa_apply(p: dict, x: jnp.ndarray, *, num_heads: int, num_kv_heads: int,

@@ -17,6 +17,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..obs.profile import scope
 from .config import SSMConfig
 from .layers import dense_init, matmul, rmsnorm, rmsnorm_init
 
@@ -133,13 +134,15 @@ def _ssd_chunked(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
 
 def ssd_chunked(x, dt, A, Bm, Cm, chunk: int, init_state=None):
     """Flash-style memory: the O(Q²) intra-chunk decay matrices are
-    recomputed in the backward pass, never saved."""
+    recomputed in the backward pass, never saved.  Ops under the named
+    scope ``model.ssd``."""
     import functools
     inner = functools.partial(_ssd_chunked, chunk=chunk)
     inner = jax.checkpoint(inner, policy=jax.checkpoint_policies.nothing_saveable)
-    if init_state is None:
-        return inner(x, dt, A, Bm, Cm)
-    return inner(x, dt, A, Bm, Cm, init_state=init_state)
+    with scope("model.ssd"):
+        if init_state is None:
+            return inner(x, dt, A, Bm, Cm)
+        return inner(x, dt, A, Bm, Cm, init_state=init_state)
 
 
 def mamba_apply(p: dict, xin: jnp.ndarray, s: SSMConfig,

@@ -14,10 +14,15 @@ Disabled-by-default guarantee
 The global bus starts as :data:`NULL`, a no-op singleton whose methods
 do nothing and allocate nothing (``enabled = False``).  Instrumented
 code either calls the no-op methods directly (~a method call per round)
-or guards bigger argument construction behind ``bus.enabled`` — both
-are far below measurement noise per training step, and the telemetry
-overhead benchmark (``benchmarks/slot_runtime``) gates the end-to-end
-cost at < 2% of steps/s.
+or guards bigger argument construction behind ``bus.enabled``.
+
+Spans have a second sink: :meth:`Telemetry.span` and
+:meth:`NullTelemetry.span` both enter a profiler annotation
+(:func:`repro.obs.profile.annotation`) named after the span, so every
+span lands on the profiler's host plane, on the device planes' clock,
+whenever a profile is being captured — whether or not the bus is on.
+With no profile captured and the bus off a span costs that annotation
+alone, under a microsecond on the host.
 
 Clock
 -----
@@ -33,6 +38,8 @@ import dataclasses
 import time
 from contextlib import contextmanager
 from typing import Any, Dict, Iterator, List, Optional, Tuple
+
+from .profile import annotation
 
 _CLOCK = time.perf_counter
 
@@ -94,7 +101,8 @@ class Telemetry:
     * :meth:`observe` — histogram samples (``"overlay.rebuild_ms"``);
     * :meth:`event` — timestamped structured events;
     * :meth:`span` — a context manager timing a host-side block, which
-      feeds both a ``<name>.ms`` histogram and (optionally) an event.
+      feeds both a ``<name>.ms`` histogram and (optionally) an event,
+      and labels the block on the profiler timeline.
 
     Naming convention: ``<layer>.<signal>`` with ``_ms`` / ``_bytes``
     suffixes on units — the round ledger (:mod:`repro.obs.rounds`)
@@ -114,6 +122,7 @@ class Telemetry:
         self.events: List[TelemetryEvent] = []
         self.max_events = max_events
         self.dropped_events = 0
+        self._span_attrs: Dict[str, Any] = {}   # of the open spans
 
     # ---- instruments -----------------------------------------------------
     def count(self, name: str, n: float = 1) -> None:
@@ -136,13 +145,23 @@ class Telemetry:
 
     @contextmanager
     def span(self, name: str, **attrs) -> Iterator[None]:
-        """Time a host-side block into the ``<name>.ms`` histogram (and
-        an event when attributes are given)."""
+        """Time a host-side block into the ``<name>.ms`` histogram, and
+        into an event (stamped at the block's end) when it carries
+        attributes.  A span inherits the attributes of the spans open
+        around it, so the phases of one ``slot.round`` all carry its
+        ``round``.  The block is also a profiler annotation."""
+        outer = self._span_attrs
+        if attrs:
+            self._span_attrs = attrs = {**outer, **attrs}
+        else:
+            attrs = outer
         t0 = _CLOCK()
         try:
-            yield
+            with annotation(name):
+                yield
         finally:
             ms = (_CLOCK() - t0) * 1e3
+            self._span_attrs = outer
             self.observe(name + ".ms", ms)
             if attrs:
                 self.event(name, ms=round(ms, 4), **attrs)
@@ -172,23 +191,12 @@ class Telemetry:
         return out
 
 
-class _NullSpan:
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
-
-
 class NullTelemetry(Telemetry):
-    """The disabled bus: every method is a no-op and nothing is ever
-    allocated.  This is the process-global default — telemetry is
-    strictly opt-in (:func:`enable` / an explicit ``telemetry=``)."""
+    """The disabled bus: every method is a no-op and the bus holds no
+    state.  :meth:`span` still enters the profiler annotation, so a
+    captured profile shows the spans of a run with the bus off.  This is
+    the process-global default — telemetry is strictly opt-in
+    (:func:`enable` / an explicit ``telemetry=``)."""
 
     enabled = False
 
@@ -208,7 +216,7 @@ class NullTelemetry(Telemetry):
         pass
 
     def span(self, name, **attrs):
-        return _NULL_SPAN
+        return annotation(name)
 
     def snapshot(self):
         return {}

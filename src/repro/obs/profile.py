@@ -9,9 +9,11 @@ Two kinds of label, matching where the cost lives:
   while tracing, so it is safe on the hottest path and cannot disturb
   fusion or retrace behavior.
 * :func:`annotation` — ``jax.profiler.TraceAnnotation``.  A **runtime**
-  host-side label for the profiler timeline (host rows).  Used at
-  step/swap boundaries only (controller rebuilds, loop steps), never
-  inside jitted code.
+  host-side label for the profiler timeline (host rows), on the same
+  clock as the device planes.  Every
+  :meth:`~repro.obs.events.Telemetry.span` enters one, so the loop's and
+  the controller's spans land in any profile being captured.  Used at
+  step/swap boundaries only, never inside jitted code.
 
 :func:`capture` wraps ``jax.profiler.trace``: pass a directory to get a
 TensorBoard-loadable profile of the ``with`` body, pass None to no-op —

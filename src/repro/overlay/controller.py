@@ -395,40 +395,45 @@ class OverlayController:
         The schedule is a pure function of the alive set (+ profiles),
         so only *membership* deltas force a rebuild; pointer-only deltas
         (NDMP repair in flight) advance the epoch without paying the
-        host-side rebuild for a byte-identical schedule."""
-        t_end = self.sim.now + dt
-        due = list(events)
-        if trace is not None:
-            due.extend(trace.between(self._applied_until, t_end))
-        self._applied_until = max(self._applied_until, t_end)
-        ChurnTrace.apply(self.sim, sorted(due, key=lambda e: e.time))
-        self.sim.run_until(t_end)
-        if self.repair_policy is not None:
-            self._repair_retry()
-        delta = self.tracker.poll()
-        if self._staged is None:
-            self.last_plan = None
-        swapped, rebuilt, cache_hit, rebuild_ms, alive = self._refresh(
-            force=bool(delta.joined or delta.left))
+        host-side rebuild for a byte-identical schedule.
+
+        The interval is the ``overlay.step`` span of the bus (a profiler
+        annotation even with the bus off)."""
         bus = get_telemetry()
-        if bus.enabled:   # host-side, step-boundary only (repro.obs contract)
-            if delta.joined:
-                bus.count("overlay.churn_joins", len(delta.joined))
-            if delta.left:
-                bus.count("overlay.churn_leaves", len(delta.left))
-            if rebuilt:
-                bus.count("overlay.rebuilds")
-                bus.observe("overlay.rebuild_ms", rebuild_ms)
-            if swapped:
-                bus.count("overlay.swaps")
-            bus.count("overlay.cache_hits" if cache_hit
-                      else "overlay.cache_misses")
-        return ControlReport(
-            epoch=self.tracker.epoch, time=self.sim.now,
-            alive=alive, delta=delta, swapped=swapped,
-            rebuilt=rebuilt, cache_hit=cache_hit, rebuild_ms=rebuild_ms,
-            correctness=(self.sim.correctness()
-                         if self.measure_correctness else None))
+        with bus.span("overlay.step"):
+            t_end = self.sim.now + dt
+            due = list(events)
+            if trace is not None:
+                due.extend(trace.between(self._applied_until, t_end))
+            self._applied_until = max(self._applied_until, t_end)
+            ChurnTrace.apply(self.sim, sorted(due, key=lambda e: e.time))
+            self.sim.run_until(t_end)
+            if self.repair_policy is not None:
+                self._repair_retry()
+            delta = self.tracker.poll()
+            if self._staged is None:
+                self.last_plan = None
+            swapped, rebuilt, cache_hit, rebuild_ms, alive = self._refresh(
+                force=bool(delta.joined or delta.left))
+            # host-side, step-boundary only (repro.obs contract)
+            if bus.enabled:
+                if delta.joined:
+                    bus.count("overlay.churn_joins", len(delta.joined))
+                if delta.left:
+                    bus.count("overlay.churn_leaves", len(delta.left))
+                if rebuilt:
+                    bus.count("overlay.rebuilds")
+                    bus.observe("overlay.rebuild_ms", rebuild_ms)
+                if swapped:
+                    bus.count("overlay.swaps")
+                bus.count("overlay.cache_hits" if cache_hit
+                          else "overlay.cache_misses")
+            return ControlReport(
+                epoch=self.tracker.epoch, time=self.sim.now,
+                alive=alive, delta=delta, swapped=swapped,
+                rebuilt=rebuilt, cache_hit=cache_hit, rebuild_ms=rebuild_ms,
+                correctness=(self.sim.correctness()
+                             if self.measure_correctness else None))
 
     def commit(self):
         """Apply the staged swap at the step boundary (no-op unless
@@ -440,29 +445,31 @@ class OverlayController:
 
         :attr:`last_commit_ms` afterwards holds the host time the swap
         took (0 when nothing was staged) — the per-round commit-latency
-        fact the :class:`repro.obs.rounds.RoundLedger` records."""
-        if self._staged is not None:
-            if self.swap_barrier is not None:
-                try:
-                    self.swap_barrier()
-                except Exception:
-                    # a peer missed the boundary: keep serving the live
-                    # mixer, leave the swap staged for the next commit
-                    self.swap_barrier_aborts += 1
-                    get_telemetry().count("faults.swap_barrier_aborts")
-                    self.last_commit_ms = 0.0
-                    return self.last_plan
-            staged, self._staged = self._staged, None
-            t0 = _time.perf_counter()
-            self._apply(staged)
-            self.last_commit_ms = (_time.perf_counter() - t0) * 1e3
-            bus = get_telemetry()
-            if bus.enabled:
-                bus.count("overlay.commits")
-                bus.observe("overlay.commit_ms", self.last_commit_ms)
-        else:
-            self.last_commit_ms = 0.0
-        return self.last_plan
+        fact the :class:`repro.obs.rounds.RoundLedger` records.  The
+        whole call is the ``overlay.commit`` span."""
+        bus = get_telemetry()
+        with bus.span("overlay.commit"):
+            if self._staged is not None:
+                if self.swap_barrier is not None:
+                    try:
+                        self.swap_barrier()
+                    except Exception:
+                        # a peer missed the boundary: keep serving the live
+                        # mixer, leave the swap staged for the next commit
+                        self.swap_barrier_aborts += 1
+                        bus.count("faults.swap_barrier_aborts")
+                        self.last_commit_ms = 0.0
+                        return self.last_plan
+                staged, self._staged = self._staged, None
+                t0 = _time.perf_counter()
+                self._apply(staged)
+                self.last_commit_ms = (_time.perf_counter() - t0) * 1e3
+                if bus.enabled:
+                    bus.count("overlay.commits")
+                    bus.observe("overlay.commit_ms", self.last_commit_ms)
+            else:
+                self.last_commit_ms = 0.0
+            return self.last_plan
 
     # ---- internals -------------------------------------------------------
     def _repair_retry(self) -> bool:
@@ -510,37 +517,38 @@ class OverlayController:
             alive = (self._staged.alive if self._staged is not None
                      else self._alive)
             return False, False, hit, 0.0, alive
-        t0 = _time.perf_counter()
-        addrs = self._alive_addresses()
-        alive = tuple(a.node_id for a in addrs)
-        profiles = (self.profiles_fn(alive)
-                    if self.profiles_fn is not None else None)
-        alive_sched = schedule_from_addresses(
-            addrs, profiles=profiles, alpha_d=self.alpha_d,
-            alpha_c=self.alpha_c,
-            confidence_weighted=self.confidence_weighted)
-        plan = None
-        sched = alive_sched
-        if self.slots is not None:
-            from ..core.mixing import pad_schedule
-            plan = self.slots.plan(alive)
-            slot_of = plan.slot_of
-            sched = pad_schedule(alive_sched,
-                                 [slot_of[u] for u in alive],
-                                 self.capacity)
-        rebuild_ms = (_time.perf_counter() - t0) * 1e3
-        self.rebuilds += 1
-        mixer, hit = self.cache.get(sched, self.fuse, self.codec)
-        swapped = sched != self._schedule
-        if swapped:
-            self.swaps += 1
-        staged = _StagedSwap(alive=alive, alive_schedule=alive_sched,
-                             schedule=sched, mixer=mixer, plan=plan)
-        if self.double_buffered:
-            self._staged = staged
-        else:
-            self._apply(staged)
-        return swapped, True, hit, rebuild_ms, alive
+        with get_telemetry().span("overlay.rebuild"):
+            t0 = _time.perf_counter()
+            addrs = self._alive_addresses()
+            alive = tuple(a.node_id for a in addrs)
+            profiles = (self.profiles_fn(alive)
+                        if self.profiles_fn is not None else None)
+            alive_sched = schedule_from_addresses(
+                addrs, profiles=profiles, alpha_d=self.alpha_d,
+                alpha_c=self.alpha_c,
+                confidence_weighted=self.confidence_weighted)
+            plan = None
+            sched = alive_sched
+            if self.slots is not None:
+                from ..core.mixing import pad_schedule
+                plan = self.slots.plan(alive)
+                slot_of = plan.slot_of
+                sched = pad_schedule(alive_sched,
+                                     [slot_of[u] for u in alive],
+                                     self.capacity)
+            rebuild_ms = (_time.perf_counter() - t0) * 1e3
+            self.rebuilds += 1
+            mixer, hit = self.cache.get(sched, self.fuse, self.codec)
+            swapped = sched != self._schedule
+            if swapped:
+                self.swaps += 1
+            staged = _StagedSwap(alive=alive, alive_schedule=alive_sched,
+                                 schedule=sched, mixer=mixer, plan=plan)
+            if self.double_buffered:
+                self._staged = staged
+            else:
+                self._apply(staged)
+            return swapped, True, hit, rebuild_ms, alive
 
     def _apply(self, staged: _StagedSwap) -> None:
         """Make a staged swap live (slot remap, schedule, mixer)."""

@@ -611,56 +611,68 @@ class SlotTrainLoop:
 
     def _run(self, num_steps, trace, jnp, ctl,
              get_telemetry, get_round_ledger) -> List[SlotStepRecord]:
+        # each phase is a span of the bus (a profiler annotation even
+        # with the bus off); ``slot.loss_wait`` is the one place the
+        # host waits on the device
+        bus = get_telemetry()
         for _ in range(num_steps):
             step = self._step
-            report = ctl.step(self.step_time, trace=trace)
-            plan = ctl.commit()          # swap lands at the step boundary
-            joined, left = ((), ())
-            if plan is not None and plan.changed:
-                joined, left = self._apply_plan(plan)
-            alive = ctl.alive
-            alive_mask = ctl.alive_mask()
-            mask = self._shard_rows(jnp.asarray(alive_mask))
-            mix_mask = self._shard_rows(
-                jnp.asarray(self._mix_mask(alive, alive_mask, step)))
-            batch = self._shard_rows(self._capacity_batch(alive, step))
-            em_np, degraded = self._edge_mask(report.time)
-            params, opt_state, metrics = self.local_step(
-                self.params, self.opt_state, batch, mask)
-            # the hot-swap seam: the controller's mask-aware mixer; slow
-            # or dead slots pass through untouched.  EF codecs thread
-            # the residual leaf through the round.  Under a fault plane
-            # the edge mask is passed every round (even all-ones, so the
-            # arity — and thus the trace — never changes mid-run).
-            mkw = ({} if em_np is None
-                   else {"edge_mask": self._shard_rows(jnp.asarray(em_np))})
-            if self.ef:
-                mixed, res = ctl.mixer(params, mix_mask, self.residual,
-                                       **mkw)
-                self.residual = self._shard_rows(res)
-            else:
-                mixed = ctl.mixer(params, mix_mask, **mkw)
-            self.params = self._shard_rows(mixed)
-            self.opt_state = self._shard_rows(opt_state)
-            part = int(np.asarray(mix_mask).sum())
-            loss = float(np.asarray(metrics["loss"]))
-            self.records.append(SlotStepRecord(
-                step=step, time=report.time, num_alive=len(alive),
-                participating=part, loss=loss,
-                swapped=report.swapped, cache_hit=report.cache_hit,
-                joined=joined, left=left))
-            bus = (self._telemetry if self._telemetry is not None
-                   else get_telemetry())
-            if bus.enabled:
-                bus.count("slot.steps")
-                bus.gauge("slot.num_alive", len(alive))
-                bus.gauge("slot.participating", part)
-            ledger = (self._ledger if self._ledger is not None
-                      else get_round_ledger())
-            if ledger is not None:
-                self._record_round(ledger, step, report, part, loss,
-                                   joined, left,
-                                   faults_injected=self._faults_injected(),
-                                   degraded_edges=degraded)
+            with bus.span("slot.round", round=step):
+                report = ctl.step(self.step_time, trace=trace)
+                plan = ctl.commit()      # swap lands at the step boundary
+                joined, left = ((), ())
+                if plan is not None and plan.changed:
+                    with bus.span("slot.apply_plan"):
+                        joined, left = self._apply_plan(plan)
+                with bus.span("slot.batch"):
+                    alive = ctl.alive
+                    alive_mask = ctl.alive_mask()
+                    mix_np = self._mix_mask(alive, alive_mask, step)
+                    mask = self._shard_rows(jnp.asarray(alive_mask))
+                    mix_mask = self._shard_rows(jnp.asarray(mix_np))
+                    batch = self._shard_rows(
+                        self._capacity_batch(alive, step))
+                    em_np, degraded = self._edge_mask(report.time)
+                with bus.span("slot.step"):
+                    params, opt_state, metrics = self.local_step(
+                        self.params, self.opt_state, batch, mask)
+                # the hot-swap seam: the controller's mask-aware mixer;
+                # slow or dead slots pass through untouched.  EF codecs
+                # thread the residual leaf through the round.  Under a
+                # fault plane the edge mask is passed every round (even
+                # all-ones, so the arity — and thus the trace — never
+                # changes mid-run).
+                with bus.span("slot.mix"):
+                    mkw = ({} if em_np is None else
+                           {"edge_mask": self._shard_rows(
+                               jnp.asarray(em_np))})
+                    if self.ef:
+                        mixed, res = ctl.mixer(params, mix_mask,
+                                               self.residual, **mkw)
+                        self.residual = self._shard_rows(res)
+                    else:
+                        mixed = ctl.mixer(params, mix_mask, **mkw)
+                    self.params = self._shard_rows(mixed)
+                    self.opt_state = self._shard_rows(opt_state)
+                with bus.span("slot.loss_wait"):
+                    loss = float(np.asarray(metrics["loss"]))
+                with bus.span("slot.record"):
+                    part = int(mix_np.sum())
+                    self.records.append(SlotStepRecord(
+                        step=step, time=report.time, num_alive=len(alive),
+                        participating=part, loss=loss,
+                        swapped=report.swapped, cache_hit=report.cache_hit,
+                        joined=joined, left=left))
+                    if bus.enabled:
+                        bus.count("slot.steps")
+                        bus.gauge("slot.num_alive", len(alive))
+                        bus.gauge("slot.participating", part)
+                    ledger = (self._ledger if self._ledger is not None
+                              else get_round_ledger())
+                    if ledger is not None:
+                        self._record_round(
+                            ledger, step, report, part, loss, joined, left,
+                            faults_injected=self._faults_injected(),
+                            degraded_edges=degraded)
             self._step += 1
         return self.records

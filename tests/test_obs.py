@@ -24,6 +24,7 @@ Pinned here:
 """
 
 import json
+import os
 
 import jax
 import jax.numpy as jnp
@@ -75,6 +76,23 @@ def test_bus_span_times_into_histogram():
     with bus.span("overlay.commit", slot=2):
         pass
     assert bus.events and bus.events[0].attrs["slot"] == 2
+
+
+def test_nested_spans_inherit_the_open_spans_attributes():
+    bus = Telemetry()
+    with bus.span("slot.round", round=7):
+        with bus.span("slot.step"):
+            pass
+        with bus.span("overlay.step", epoch=2):
+            pass
+    with bus.span("slot.round"):
+        pass
+    got = [(e.name, {k: v for k, v in e.attrs.items() if k != "ms"})
+           for e in bus.events]
+    assert got == [("slot.step", {"round": 7}),
+                   ("overlay.step", {"round": 7, "epoch": 2}),
+                   ("slot.round", {"round": 7})]
+    assert bus.histograms["slot.round.ms"].count == 2
 
 
 def test_bus_event_cap_drops_not_grows():
@@ -204,6 +222,53 @@ def test_capture_writes_profile(tmp_path):
     with capture(log_dir):
         jax.block_until_ready(jnp.arange(8) * 2)
     assert log_dir.exists() and any(log_dir.rglob("*"))
+
+
+def _host_event_names(log_dir):
+    from jax.profiler import ProfileData
+    (path,) = log_dir.rglob("*.xplane.pb")
+    data = ProfileData.from_file(str(path))
+    return [e.name for plane in data.planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events]
+
+
+def test_spans_land_on_the_profile_host_plane_with_the_bus_on_or_off(
+        tmp_path):
+    bus = Telemetry()
+    with capture(tmp_path / "prof"):
+        with bus.span("slot.x", round=1):
+            jax.block_until_ready(jnp.arange(8) * 2)
+        with NULL.span("slot.y"):
+            jax.block_until_ready(jnp.arange(8) * 3)
+    names = _host_event_names(tmp_path / "prof")
+    assert names.count("slot.x") == 1 and names.count("slot.y") == 1
+    assert bus.histograms["slot.x.ms"].count == 1
+    # the disabled bus kept no state
+    assert not vars(NULL)
+
+
+def test_the_compile_cache_key_covers_the_named_scopes(tmp_path,
+                                                       monkeypatch):
+    """An executable compiled without a scope must not be found again
+    for the scoped program: its ops would reach the profiler unnamed."""
+    from repro.launch.compile_cache import ROOT, enable_compile_cache
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    keys = ("jax_compilation_cache_include_metadata_in_key",
+            "jax_hlo_source_file_canonicalization_regex")
+    before = {k: getattr(jax.config, k) for k in keys}
+    try:
+        assert enable_compile_cache() == str(tmp_path)
+        assert getattr(jax.config, keys[0]) is True
+        # source files enter the key relative to the checkout's root, so
+        # a second checkout of the same code finds the same entries
+        text = jax.jit(lambda x: x * 3.0).lower(1.0).as_text(
+            debug_info=True)
+        assert '"tests/test_obs.py"' in text
+        assert ROOT + os.sep not in text
+    finally:
+        for k, v in before.items():
+            jax.config.update(k, v)
 
 
 # --------------------------------------------------------------------------
