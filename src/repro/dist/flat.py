@@ -30,10 +30,15 @@ Pallas kernel streaming tiles through VMEM.
   (bf16/f16/f32 into f32 — params trees).  Wider or non-float leaves
   are rejected loudly rather than rounded silently.
 
-:func:`map_dtype_buffers` is the codec-free mixer's layout: one buffer
-per leaf dtype, each in that dtype, so a bf16 model's round holds a
-bf16 population.  The resident ``flat_io`` and error-feedback layouts of
-:class:`repro.runtime.SlotTrainLoop` still use one f32 buffer.
+The codec, error-feedback and ``flat_io`` rounds need this row: the
+wire codecs encode one contiguous row, and the resident ``flat_io`` and
+error-feedback layouts of :class:`repro.runtime.SlotTrainLoop` keep one
+f32 buffer.  Codec-free rounds do not ravel at all while the client
+count is within :data:`repro.kernels.weighted_mix.LEAF_MIX_MAX_CLIENTS`:
+mixing is linear and per parameter, so they mix each leaf in its own
+layout (:func:`repro.kernels.weighted_mix.gather_mix_leaf`).  Above it,
+:func:`map_dtype_buffers` is their layout: one buffer per leaf dtype,
+each in that dtype, so a bf16 model's round holds a bf16 population.
 
 Specs are pure shape/dtype metadata (hashable, built at trace time from
 tracers), so a jitted mixer rebuilds its spec deterministically per

@@ -50,9 +50,13 @@ lane-aligned offsets) and the whole round runs on that buffer with the
   row streams into the accumulator via the incremental
   :func:`~repro.kernels.weighted_mix.mix_accumulate` entry, so only
   {own, acc, current receive} ever exist at once, independent of 2L;
-* global path — one :func:`~repro.kernels.weighted_mix.gather_mix`
-  kernel per round over the resident (C, N) population buffer: static
-  source rows (the schedule's perms), runtime weight table.  Masking
+* global path — one round-mixing kernel per round: static source rows
+  (the schedule's perms), runtime weight table.  Codec-free rounds with
+  C ≤ :data:`~repro.kernels.weighted_mix.LEAF_MIX_MAX_CLIENTS` run
+  :func:`~repro.kernels.weighted_mix.gather_mix_leaf` on each leaf in
+  its own layout and ravel nothing; larger populations, codecs and
+  ``flat_io`` run :func:`~repro.kernels.weighted_mix.gather_mix` over
+  the (C, N) population buffer.  Masking
   (dead capacity slots, multirate skips) only rewrites the (C, 2L+1)
   weight table — renormalizing over surviving sources, identity rows
   for dead clients — with **zero retrace**.  Note GSPMD treats the
@@ -79,7 +83,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.mixing import PermuteSchedule, check_group_size, grouped_routing
-from ..kernels.weighted_mix import gather_mix, mix_accumulate
+from ..kernels.weighted_mix import (LEAF_MIX_MAX_CLIENTS, gather_mix,
+                                    gather_mix_leaf, mix_accumulate,
+                                    round_matrix)
 from ..obs.events import get_telemetry
 from ..obs.profile import scope
 from ..wire.codec import WireCodec, get_codec
@@ -428,15 +434,24 @@ def global_mixer(strategy: str,
     storms never retrace.
 
     ``fuse="flat"`` (fedlay/ring) replaces the per-leaf permutation
-    gathers with **one Pallas kernel per round** over the raveled
-    (C, N) population buffer
-    (:func:`repro.kernels.weighted_mix.gather_mix`): the schedule's
-    perms become a static (C, 2L+1) source-row table (column 0 = self)
-    and the confidence weights a runtime (C, 2L+1) table.  The masked
-    variant only rewrites that weight table — renormalized over
-    surviving sources, identity rows for dead/starved clients — so the
-    mask stays a zero-retrace runtime input.  allreduce/none have no
-    per-slot accumulate to fuse and ignore ``fuse``.
+    gathers with Pallas round-mixing kernels: the schedule's perms
+    become a static (C, 2L+1) source-row table (column 0 = self) and
+    the confidence weights a runtime (C, 2L+1) table.  Codec-free, the
+    path follows the client count C read off the leaves: at C ≤
+    :data:`repro.kernels.weighted_mix.LEAF_MIX_MAX_CLIENTS` the table is
+    scattered into the runtime (C, C) round matrix and **one kernel per
+    leaf** mixes each leaf in its own shape and layout
+    (:func:`repro.kernels.weighted_mix.gather_mix_leaf`: an f32 VPU
+    multiply-add, nothing raveled, padded or concatenated); above it
+    **one kernel per leaf dtype** runs over the raveled (C, N)
+    population buffer (:func:`repro.kernels.weighted_mix.gather_mix`,
+    whose C² flops ride the MXU).  Each compile ticks ``mix.leafwise``
+    or ``mix.flat`` on the telemetry bus.  Codec rounds always ravel
+    into one f32 row for the wire.  The masked variant only rewrites
+    the weight table — renormalized over surviving sources, identity
+    rows for dead/starved clients — so the mask stays a zero-retrace
+    runtime input.  allreduce/none have no per-slot accumulate to fuse
+    and ignore ``fuse``.
 
     ``codec`` (:mod:`repro.wire.codec`; implies ``fuse="flat"``)
     compresses the fedlay/ring round: the population buffer is encoded
@@ -532,12 +547,25 @@ def global_mixer(strategy: str,
 
         if fuse == "flat":
             # (C, 2L+1) static source rows: self first, then the 2L
-            # schedule sources — one gather_mix kernel mixes the round.
+            # schedule sources — the round's kernels mix through them.
             srcs = np.concatenate(
                 [np.arange(C)[:, None], np.array(sched.perms).T], axis=1)
             base_table = jnp.concatenate(
                 [self_w[:, None], weights], axis=1).astype(jnp.float32)
             ef = codec is not None and codec.error_feedback
+
+            def masked_table(mask, edge_mask=None):
+                """(table (C, 2L+1), ok (C,)) of a masked round."""
+                sw, ew, ok = masked_tables(mask, edge_mask)
+                return jnp.concatenate([sw[:, None], ew], axis=1), ok
+
+            def codec_free_table(table, ok=None):
+                """The round's (C, 2L+1) table with identity rows where
+                ~ok: a codec-free round mixes every row through it."""
+                if ok is None:
+                    return table
+                ident = jnp.zeros_like(table).at[:, 0].set(1.0)
+                return jnp.where(ok[:, None], table, ident)
 
             def round_flat(buf, table, ok=None, residual=None):
                 """One fused round on the (C, N) buffer → (out, res).
@@ -548,11 +576,9 @@ def global_mixer(strategy: str,
                 returns the fresh residual (mask-gating is the
                 caller's)."""
                 if codec is None:
-                    if ok is not None:
-                        ident = jnp.zeros_like(table).at[:, 0].set(1.0)
-                        table = jnp.where(ok[:, None], table, ident)
                     with scope("global_mixer.gather_mix"):
-                        return gather_mix(buf, srcs, table), None
+                        return gather_mix(buf, srcs,
+                                          codec_free_table(table, ok)), None
                 bus = get_telemetry()           # trace-time tick (see
                 bus.count("wire.encodes")       # fedlay_mix): counts
                 bus.count("wire.decodes")       # codec (re)compiles
@@ -573,16 +599,14 @@ def global_mixer(strategy: str,
                 return round_flat(buf, base_table)[0]
 
             def mix_buf_masked(buf, mask, *, edge_mask=None):
-                sw, ew, ok = masked_tables(mask, edge_mask)
-                table = jnp.concatenate([sw[:, None], ew], axis=1)
+                table, ok = masked_table(mask, edge_mask)
                 return round_flat(buf, table, ok=ok)[0]
 
             def mix_buf_ef(buf, residual):
                 return round_flat(buf, base_table, residual=residual)
 
             def mix_buf_masked_ef(buf, mask, residual, *, edge_mask=None):
-                sw, ew, ok = masked_tables(mask, edge_mask)
-                table = jnp.concatenate([sw[:, None], ew], axis=1)
+                table, ok = masked_table(mask, edge_mask)
                 out, res = round_flat(buf, table, ok=ok, residual=residual)
                 # masked-out rows (dead slots, multirate skips) keep
                 # their residual: they contributed nothing this round
@@ -608,11 +632,25 @@ def global_mixer(strategy: str,
                     spec = FlatSpec.for_tree(params)
                     return spec.unravel(inner(spec.ravel(params), *rest,
                                               **kw))
-                # codec-free rounds mix each leaf dtype in a buffer of
-                # that dtype: the kernel accumulates in f32 and rounds
-                # once either way, so results are those of one f32
+                # trace-time tick: which codec-free path compiled
+                bus = get_telemetry()
+                n = jax.tree.leaves(params)[0].shape[0]
+                if n <= LEAF_MIX_MAX_CLIENTS:
+                    # mixing is linear and per parameter: each leaf is
+                    # mixed in its own layout, nothing raveled
+                    bus.count("mix.leafwise")
+                    table = (codec_free_table(*masked_table(*rest, **kw))
+                             if masked else base_table)
+                    W = round_matrix(n, srcs, table)
+                    with scope("global_mixer.gather_mix"):
+                        return jax.tree.map(
+                            lambda leaf: gather_mix_leaf(leaf, W), params)
+                # past the VPU bound the C² flops go to the MXU kernel
+                # over one (C, N) buffer per leaf dtype: f32 accumulation
+                # and one rounding, so results are those of one f32
                 # buffer, while a bf16 model's round holds a bf16
-                # population (half the HBM) instead of an f32 one
+                # population
+                bus.count("mix.flat")
                 return map_dtype_buffers(
                     params, lambda buf: inner(buf, *rest, **kw))
             return mix_flat

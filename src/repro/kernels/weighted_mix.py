@@ -26,15 +26,24 @@ shapes the mixing paths produce (see :mod:`repro.dist.sync`):
   masks renormalize with zero retrace).  One kernel per mixing round:
   each column tile of the population is read once and serves every
   output row — HBM traffic 2·C·N regardless of the overlay degree, and
-  no materialized receive temporaries at all.
+  no materialized receive temporaries at all.  It mixes the codec and
+  ``flat_io`` rows, and codec-free rounds above
+  :data:`LEAF_MIX_MAX_CLIENTS` clients.
+* :func:`gather_mix_leaf` — the same round on one parameter leaf in
+  its own shape and layout, for codec-free rounds of up to
+  :data:`LEAF_MIX_MAX_CLIENTS` clients: the (C, C) round matrix
+  (:func:`round_matrix`) in SMEM, the sum over sources an f32 VPU
+  multiply-add, the leaf tiled over its own last two dimensions, so a
+  round ravels, pads and concatenates nothing.
 
-Grids are 1-D over ceil(N/BN) lane-aligned tiles; K (≤ ~13: self + 2L
-neighbors) and C (clients per controller, ≤ a few dozen) ride whole in
-VMEM per tile.  BN need not divide N: Pallas masks the part of the last
-tile that hangs past the array (columns are independent, so it never
-reaches an in-range output).  A lane-aligned buffer — every
-:class:`repro.dist.flat.FlatSpec` buffer — is therefore read and written
-in place; only a width that is not a lane multiple pays a padded copy.
+The flat entries' grids are 1-D over ceil(N/BN) lane-aligned tiles; K
+(≤ ~13: self + 2L neighbors) and C (clients per controller, ≤ a few
+dozen) ride whole in VMEM per tile.  BN need not divide N: Pallas masks
+the part of the last tile that hangs past the array (columns are
+independent, so it never reaches an in-range output).  A lane-aligned
+buffer — every :class:`repro.dist.flat.FlatSpec` buffer — is therefore
+read and written in place; only a width that is not a lane multiple
+pays a padded copy.
 The MXU is idle — these kernels live on the VPU — so tiles are sized
 for bandwidth, not matmul alignment.  ``interpret``
 defaults to auto (:func:`repro.kernels.interpret.resolve_interpret`):
@@ -44,12 +53,15 @@ everywhere else.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.layout import Layout
+from jax.experimental.pallas import tpu as pltpu
 
 from ..obs.profile import scope
 from .interpret import resolve_interpret
@@ -320,3 +332,165 @@ def gather_mix(buf: jnp.ndarray, srcs, weights: jnp.ndarray,
             interpret=interp,
         )(W, bufs)
     return out if Np == N else out[:, :N]
+
+
+#: Largest client count whose round the leaf-native mix
+#: (:func:`gather_mix_leaf`) runs on the VPU under the memory bound.
+#: Per parameter it moves 2·C·s bytes (s the itemsize) and issues about
+#: 2·C² + C f32 vector operations over 1,024 lanes (C² multiplies,
+#: C² − C adds, 2·C converts).  ``chipbench/peaks.json`` gives a v5e
+#: 819 GB/s of HBM and 197 TFLOP/s of bf16 MXU, i.e. 4 MXUs × 128² × 2
+#: flops a cycle at 1.5 GHz: at k vector operations a cycle the VPU's
+#: time, (2·C² + C) / (k × 1,024 × 1.5e9) s, stays under the memory
+#: time, 2·C·s / 819e9 s, while 2·C + 1 ≤ 3.75·k·s.  For bf16 (s = 2)
+#: that is C ≤ 3 at k = 1, C ≤ 7 at k = 2 and C ≤ 10 at k = 3.  On a
+#: v5e the kernel mixed mamba2-370m's bf16 ``in_proj`` at 79 % of the
+#: HBM roofline with C = 2, 80 % with C = 4 and 72–76 % with C = 8, so
+#: the VPU keeps pace up to 8; above it the (C, N) kernel
+#: (:func:`gather_mix`) mixes the round with its C² flops on the MXU.
+LEAF_MIX_MAX_CLIENTS = 8
+
+#: VMEM bytes of one leaf-mix block: all C clients' slabs as VMEM lays
+#: them out (rows padded to the dtype's sublane tile, columns to the
+#: lane), double-buffered on the way in and out, beside the kernel's f32
+#: temporaries — within the v5e's 16 MiB of scoped VMEM.
+LEAF_BLOCK_BYTES = 2 ** 20
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _sublanes(dtype) -> int:
+    """Rows of one native TPU tile of ``dtype``: 8 for 32-bit, 16 for
+    16-bit values."""
+    return SUBLANE * 4 // jnp.dtype(dtype).itemsize
+
+
+def _layout_device():
+    return jax.devices()[0]
+
+
+def _default_order(shape, dtype) -> tuple:
+    """The dimensions of an array of ``shape``, major to minor, as the
+    device's default layout stores them.  A TPU picks them to spare
+    padding: bf16 (C, 48, 1024, 4384) is stored with 1024 minor
+    ({2,3,1,0}), (C, 3, 2560) with the client dimension second-minor."""
+    dev = _layout_device()
+    pjrt = dev.client.get_default_layout(np.dtype(dtype), tuple(shape), dev)
+    return tuple(Layout.from_pjrt_layout(pjrt).major_to_minor)
+
+
+def _leaf_view(shape, axis: int, dtype) -> tuple:
+    """A leaf in storage order (client dimension at ``axis``) as the
+    kernel reads it, without a relayout.  A (C,) leaf becomes (C, 1).
+    The dimensions between the client one and the minor one merge into
+    one row dimension only where that is a bitcast — when the
+    second-minor dimension is a whole number of sublane tiles, so that
+    no tile straddles a leading index ((C, 48, 4384, 1024) →
+    (C, 210432, 1024); (C, 48, 4, 2304) stays)."""
+    if len(shape) == 1:
+        return shape + (1,)
+    if axis < len(shape) - 3 and shape[-2] % _sublanes(dtype) == 0:
+        return (*shape[:axis + 1], int(np.prod(shape[axis + 1:-1])),
+                shape[-1])
+    return tuple(shape)
+
+
+def _leaf_blocks(view, axis: int, dtype, budget):
+    """(block_shape, grid, kernel_axis) tiling a leaf view in its own
+    layout: the client dimension (``axis``) whole in every block; the
+    last two dimensions whole, or cut into sublane-tile rows and then
+    lane-multiple columns until all C clients' slabs fit ``budget``
+    bytes as VMEM pads them; the other leading dimensions ride the grid
+    one index at a time (squeezed).  ``kernel_axis`` is where the client
+    dimension sits in the kernel's (squeezed) block."""
+    nd, C = len(view), view[axis]
+    itemsize, sub = jnp.dtype(dtype).itemsize, _sublanes(dtype)
+    r, l = view[-2], view[-1]
+    mult = C if axis < nd - 2 else 1
+
+    def nbytes(tr, tl):
+        return mult * _round_up(tr, sub) * _round_up(tl, LANE) * itemsize
+
+    tr, tl = r, l
+    if axis != nd - 2 and nbytes(r, l) > budget and r > sub:
+        row = nbytes(sub, l) // sub
+        tr = max(sub, budget // row // sub * sub)
+    if axis != nd - 1 and nbytes(tr, l) > budget and l > LANE:
+        col = nbytes(tr, LANE) // LANE
+        tl = min(l, max(LANE, budget // col // LANE * LANE))
+    block = [pl.squeezed] * (nd - 2) + [tr, tl]
+    block[axis] = C
+    grid = tuple(pl.cdiv(view[d], 1 if block[d] is pl.squeezed else
+                         block[d]) for d in range(nd) if d != axis)
+    kernel_axis = axis - sum(b is pl.squeezed for b in block[:axis])
+    return tuple(block), grid, kernel_axis
+
+
+def _leaf_mix_kernel(W_ref, x_ref, out_ref, *, axis: int):
+    # W: (C, C) f32 in SMEM; x/out: one block of every client's slab of
+    # the leaf, clients along ``axis``.  out[i] = Σ_k W[i, k]·x[k]: C²
+    # scalar × vector multiply-adds on the VPU in f32, one rounding on
+    # the store.
+    C = x_ref.shape[axis]
+
+    def client(k):
+        return (slice(None),) * axis + (pl.ds(k, 1),)
+
+    xs = [x_ref[client(k)].astype(jnp.float32) for k in range(C)]
+    for i in range(C):
+        acc = W_ref[i, 0] * xs[0]
+        for k in range(1, C):
+            acc = acc + W_ref[i, k] * xs[k]
+        out_ref[client(i)] = acc.astype(out_ref.dtype)
+
+
+def gather_mix_leaf(x: jnp.ndarray, W: jnp.ndarray,
+                    block_bytes: Optional[int] = None,
+                    interpret: Optional[bool] = None) -> jnp.ndarray:
+    """One leaf's share of a mixing round, in the leaf's own layout.
+
+    ``x`` (C, ...): every client's copy of one parameter leaf; ``W``
+    (C, C) f32 runtime round-mixing matrix (:func:`round_matrix`).
+    Returns ``out[i] = Σ_k W[i, k]·x[k]`` in ``x.dtype`` — the contract
+    of :func:`gather_mix` with the (srcs, weights) table already
+    scattered, accumulated in f32 and rounded once — without raveling.
+    Mixing is elementwise over a client's parameters, so the kernel
+    reads the leaf in the order its default layout stores it
+    (:func:`_default_order`; the transpose is a bitcast) and tiles it
+    over its own last two dimensions (:func:`_leaf_view`,
+    :func:`_leaf_blocks`): nothing is padded, concatenated, sliced or
+    relaid out at population size, and each block of all C clients is
+    read once and written once (HBM traffic 2·C·size, as the flat
+    kernel's).  ``W`` rides in SMEM as a runtime input, so masks stay
+    zero-retrace.  The sum over the C sources is an unrolled VPU
+    multiply-add, for C ≤ :data:`LEAF_MIX_MAX_CLIENTS`.  The output is
+    a fresh buffer: the mixer's input is not donated, and aliasing it
+    would cost XLA a full copy of the leaf."""
+    interp = resolve_interpret(interpret)
+    C = x.shape[0]
+    if W.shape != (C, C):
+        raise ValueError(f"W {W.shape} does not match {C} clients")
+    if block_bytes is None:
+        # interpret mode has no VMEM to fit, and its grid loop copies
+        # operands per cell: one cell holds the whole leaf
+        block_bytes = np.inf if interp else LEAF_BLOCK_BYTES
+    order = _default_order(x.shape, x.dtype)
+    stored = jnp.transpose(x, order)
+    axis = order.index(0)
+    view = _leaf_view(stored.shape, axis, x.dtype)
+    block, grid, kernel_axis = _leaf_blocks(view, axis, x.dtype,
+                                            block_bytes)
+    spec = pl.BlockSpec(block, lambda *g: tuple(
+        0 if d == axis else g[d - (d > axis)] for d in range(len(view))))
+    with scope("kernels.gather_mix"):
+        out = pl.pallas_call(
+            functools.partial(_leaf_mix_kernel, axis=kernel_axis),
+            grid=grid,
+            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), spec],
+            out_specs=spec,
+            out_shape=jax.ShapeDtypeStruct(view, x.dtype),
+            interpret=interp,
+        )(W.astype(jnp.float32), stored.reshape(view))
+    return jnp.transpose(out.reshape(stored.shape), np.argsort(order))

@@ -31,7 +31,7 @@ from repro.core.mixing import (build_permute_schedule, masked_mixing_matrix,
 from repro.dist.compat import make_client_mesh, shard_map
 from repro.dist.flat import FlatSpec
 from repro.dist.sync import check_fuse, fedlay_mix, global_mixer, make_mixer
-from repro.kernels.weighted_mix import LANE
+from repro.kernels.weighted_mix import LANE, LEAF_MIX_MAX_CLIENTS
 
 GROUPS = (1, 2, 4)
 EIGHT_DEVICES = jax.device_count() >= 8
@@ -238,17 +238,43 @@ def test_fused_global_mixer_preserves_bf16_leaves():
                                atol=2e-2)
 
 
-def test_fused_global_mixer_mixed_dtypes_match_one_f32_buffer():
-    """Codec-free flat rounds mix each leaf dtype in its own buffer; the
-    kernel accumulates in f32 and rounds once, so the result is exactly
-    that of one f32 buffer over the whole tree."""
-    C = 4
+def _assert_close(got, want, dtype, name):
+    """f32 values agree to 1e-6; a bf16 value is within one bf16 step
+    of ``want`` (f32 sums in another order may round the other way),
+    plus the f32 sum's own error where the sources cancel to about
+    zero."""
+    got = np.asarray(got, np.float32)
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(got, want, rtol=0, atol=1e-6)
+    else:
+        rounded = np.abs(np.asarray(want, np.float32)).astype(jnp.bfloat16)
+        ulp = np.spacing(rounded).astype(np.float32)
+        assert np.all(np.abs(got - want) <= ulp + 1e-6), name
+
+
+def _assert_within_one_rounding(out, ref):
+    """Two mixings of one tree agree leaf by leaf (:func:`_assert_close`)
+    and keep its dtypes."""
+    for k in ref:
+        assert out[k].dtype == ref[k].dtype
+        _assert_close(out[k], np.asarray(ref[k], np.float32), ref[k].dtype,
+                      k)
+
+
+@pytest.mark.parametrize("C", [4, 16])
+def test_fused_global_mixer_mixed_dtypes_match_one_f32_buffer(C):
+    """Codec-free flat rounds keep each leaf's dtype; the kernels
+    accumulate in f32 and round once, so the result is that of one f32
+    buffer over the whole tree: exactly, above the leaf-wise bound,
+    where each leaf dtype is mixed in its own (C, N) buffer by the same
+    kernel; within one rounding at C = 4, where each leaf is mixed in
+    its own layout on the VPU."""
     sched = build_permute_schedule(C, 2, salt="mixed")
     rng = np.random.default_rng(1)
     tree = {"a": jnp.asarray(rng.normal(size=(C, 3, 50)), jnp.bfloat16),
             "b": jnp.asarray(rng.normal(size=(C, 7)), jnp.float32),
             "c": jnp.asarray(rng.normal(size=(C, 130)), jnp.bfloat16)}
-    mask = jnp.asarray([1.0, 1.0, 0.0, 1.0])
+    mask = jnp.ones((C,)).at[2].set(0.0)
     out = jax.jit(global_mixer("fedlay", sched, masked=True,
                                fuse="flat"))(tree, mask)
     assert jax.tree.map(lambda l: l.dtype, out) == \
@@ -257,9 +283,107 @@ def test_fused_global_mixer_mixed_dtypes_match_one_f32_buffer():
     one_buffer = global_mixer("fedlay", sched, masked=True, fuse="flat",
                               flat_io=True)
     ref = spec.unravel(jax.jit(one_buffer)(spec.ravel(tree), mask))
-    for k in tree:
-        np.testing.assert_array_equal(np.asarray(out[k], np.float32),
-                                      np.asarray(ref[k], np.float32))
+    if C > LEAF_MIX_MAX_CLIENTS:
+        for k in tree:
+            np.testing.assert_array_equal(np.asarray(out[k], np.float32),
+                                          np.asarray(ref[k], np.float32))
+    else:
+        _assert_within_one_rounding(out, ref)
+
+
+def _leafwise_tree(C, seed=0):
+    """bf16 and f32 leaves of 1-D to 4-D per-client shapes: a minor
+    dimension that is no lane multiple (``in_proj``'s 4384 = 34.25
+    lanes, here 300), a second-minor dimension of 4 (``conv_w``) and one
+    that merges into rows (32 = two bf16 sublane tiles)."""
+    rng = np.random.default_rng(seed)
+
+    def leaf(shape, dt):
+        return jnp.asarray(rng.normal(size=(C,) + shape), dt)
+    return {"scalar": leaf((), jnp.float32),
+            "norm": leaf((96,), jnp.bfloat16),
+            "a_log": leaf((6, 5), jnp.float32),
+            "proj": leaf((2, 32, 300), jnp.bfloat16),
+            "conv_w": leaf((6, 4, 130), jnp.bfloat16),
+            "w": leaf((3, 16, 128), jnp.float32)}
+
+
+@pytest.mark.parametrize("C", [2, 5])
+@pytest.mark.parametrize("rounds", ["plain", "mask", "edge_mask"])
+def test_leafwise_mixer_equals_dense_oracle_and_flat_path(C, rounds):
+    """At C ≤ LEAF_MIX_MAX_CLIENTS the codec-free fused mixer mixes leaf
+    by leaf in each leaf's own layout: ≡ the dense oracle (f32 leaves to
+    1e-6, bf16 to one rounding of the f32 product) and ≡ the (C, N)
+    flat path, over bf16/f32 leaves of 1-D to 4-D per-client shapes, on
+    plain, masked and degraded (edge-masked) rounds."""
+    sched = build_permute_schedule(C, 2, salt=f"leaf{C}")
+    tree = _leafwise_tree(C, seed=C)
+    rng = np.random.default_rng(C + 11)
+    args, kw, Wd = (), {}, schedule_mixing_matrix(sched)
+    if rounds != "plain":
+        mask = np.ones(C, np.float32)
+        mask[C - 1] = 0.0
+        edge = None
+        if rounds == "edge_mask":
+            edge = (rng.random((C, sched.num_slots)) > 0.5).astype(
+                np.float32)
+            kw = {"edge_mask": jnp.asarray(edge)}
+        args, Wd = (jnp.asarray(mask),), masked_mixing_matrix(
+            sched, mask, edge_mask=edge)
+    mixer = global_mixer("fedlay", sched, masked=rounds != "plain",
+                         fuse="flat")
+    out = jax.jit(mixer)(tree, *args, **kw)
+    for k, leaf in tree.items():
+        assert out[k].dtype == leaf.dtype
+        _assert_close(np.asarray(out[k]).reshape(C, -1),
+                      Wd @ np.asarray(leaf, np.float64).reshape(C, -1),
+                      leaf.dtype, k)
+    spec = FlatSpec.for_tree(tree)                   # the (C, N) path
+    flat = global_mixer("fedlay", sched, masked=rounds != "plain",
+                        fuse="flat", flat_io=True)
+    ref = spec.unravel(jax.jit(flat)(spec.ravel(tree), *args, **kw))
+    _assert_within_one_rounding(out, ref)
+
+
+def test_leafwise_mixer_mask_changes_retrace_nothing():
+    """Masks and edge masks reach the leaf kernels as the runtime (C, C)
+    matrix: a run of distinct rounds compiles the mixer once."""
+    from repro.runtime import counting_jit
+    C = 4
+    sched = build_permute_schedule(C, 2, salt="leafjit")
+    tree = _leafwise_tree(C)
+    mix, count = counting_jit(global_mixer("fedlay", sched, masked=True,
+                                           fuse="flat"))
+    rng = np.random.default_rng(5)
+    outs = []
+    for r in range(4):
+        mask = jnp.asarray((rng.random(C) > 0.3).astype(np.float32))
+        edge = jnp.asarray((rng.random((C, sched.num_slots)) > 0.3
+                            ).astype(np.float32))
+        outs.append(mix(tree, mask, edge_mask=edge))
+    jax.block_until_ready(outs)
+    assert count.traces == 1 and count.retraces == 0
+
+
+@pytest.mark.parametrize("C", [2, LEAF_MIX_MAX_CLIENTS + 1])
+@pytest.mark.parametrize("masked", [False, True])
+def test_codec_free_mixer_counts_its_path(C, masked):
+    """Each compile of a codec-free fused mixer ticks the path the
+    client count picked: ``mix.leafwise`` at C ≤ LEAF_MIX_MAX_CLIENTS,
+    ``mix.flat`` above it."""
+    from repro.obs import telemetry
+    sched = build_permute_schedule(C, 2, salt="count")
+    tree = _leafwise_tree(C)
+    mixer = jax.jit(global_mixer("fedlay", sched, masked=masked,
+                                 fuse="flat"))
+    args = (jnp.ones((C,)),) if masked else ()
+    with telemetry() as bus:
+        mixer(tree, *args)
+        mixer(tree, *args)                          # cached: no tick
+    path = "mix.leafwise" if C <= LEAF_MIX_MAX_CLIENTS else "mix.flat"
+    other = ({"mix.leafwise", "mix.flat"} - {path}).pop()
+    assert bus.counters.get(path) == 1
+    assert other not in bus.counters
 
 
 @pytest.mark.multi_device

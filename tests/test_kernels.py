@@ -149,6 +149,65 @@ def test_gather_mix_equals_dense_product(dtype, N, bn):
                                **TOLS[dtype])
 
 
+LEAF_SHAPES = [(), (7,), (300,), (3, 5), (6, 4, 130), (2, 32, 200),
+               (5, 40, 33)]
+
+
+def _storage_orders(ndim):
+    """Row-major, and a TPU-like default layout that stores the client
+    dimension (dim 0) inside the array: the minor two dimensions
+    swapped, or the client dimension second-minor."""
+    orders = [tuple(range(ndim))]
+    if ndim >= 2:
+        orders.append(tuple(range(ndim - 2)) + (ndim - 1, ndim - 2))
+    if ndim >= 3:
+        orders.append(tuple(range(1, ndim - 1)) + (0, ndim - 1))
+    return orders
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("trailing", LEAF_SHAPES)
+@pytest.mark.parametrize("block_bytes", [None, 4096])
+def test_gather_mix_leaf_equals_dense_product(monkeypatch, dtype, trailing,
+                                              block_bytes):
+    """The leaf-native round kernel ≡ the dense W·X, for every storage
+    order a default layout may pick and with blocks small enough to tile
+    every dimension (4 KiB); dtype and shape preserved."""
+    import sys
+    from repro.kernels.weighted_mix import gather_mix_leaf
+    wm = sys.modules["repro.kernels.weighted_mix"]
+    C = 3
+    rng = np.random.default_rng(len(trailing))
+    x = jnp.asarray(rng.normal(size=(C,) + trailing), dtype)
+    W = jnp.asarray(rng.random((C, C)).astype(np.float32))
+    ref = np.einsum("ik,k...->i...", np.asarray(W, np.float64),
+                    np.asarray(x, np.float64))
+    for order in _storage_orders(x.ndim):
+        monkeypatch.setattr(wm, "_default_order", lambda s, d, o=order: o)
+        out = gather_mix_leaf(x, W, block_bytes=block_bytes,
+                              interpret=True)
+        assert out.shape == x.shape and out.dtype == x.dtype
+        np.testing.assert_allclose(np.asarray(out, np.float32), ref,
+                                   **TOLS[dtype])
+
+
+def test_gather_mix_leaf_matches_gather_mix():
+    """Scattered through round_matrix, one (srcs, weights) table mixes a
+    2-D leaf alike through both kernels."""
+    from repro.kernels.weighted_mix import (gather_mix, gather_mix_leaf,
+                                            round_matrix)
+    C, K1 = 6, 3
+    rng = np.random.default_rng(9)
+    buf = jnp.asarray(rng.normal(size=(C, 1280)), jnp.float32)
+    srcs = rng.integers(0, C, size=(C, K1))
+    w = jnp.asarray(rng.random((C, K1)).astype(np.float32))
+    np.testing.assert_allclose(
+        np.asarray(gather_mix_leaf(buf, round_matrix(C, srcs, w))),
+        np.asarray(gather_mix(buf, srcs, w)), rtol=0, atol=1e-6)
+    with pytest.raises(ValueError, match="clients"):
+        gather_mix_leaf(buf, jnp.ones((C - 1, C - 1)))
+
+
 def test_gather_mix_rejects_bad_tables():
     from repro.kernels.weighted_mix import gather_mix
     buf = jnp.ones((4, 256), jnp.float32)

@@ -13,6 +13,8 @@ import): only one process may load the TPU library, and every test
 worker imports this file.
 """
 
+import dataclasses
+import re
 import sys
 
 import jax
@@ -81,12 +83,14 @@ def flat_n(params_shape):
 
 
 @pytest.fixture
-def compiled_kernels(monkeypatch):
-    """Mixers pick interpret mode from the default backend (the CPU
-    here); compile their kernels for the described chip instead."""
-    monkeypatch.setattr(sys.modules["repro.kernels.weighted_mix"],
-                        "resolve_interpret",
+def compiled_kernels(monkeypatch, topo):
+    """Mixers pick interpret mode, and the default layouts their leaf
+    kernels read, from the default backend (the CPU here); compile their
+    kernels for the described chip, and its layouts, instead."""
+    wm = sys.modules["repro.kernels.weighted_mix"]
+    monkeypatch.setattr(wm, "resolve_interpret",
                         lambda i: False if i is None else i)
+    monkeypatch.setattr(wm, "_layout_device", lambda: topo.devices[0])
 
 
 def _compile(fn, *args):
@@ -156,21 +160,60 @@ def _stacked(params_shape, C, sharding):
 def test_flat_mixer_fits_beside_the_optimizer_state(
         one_chip, params_shape, compiled_kernels):
     """The trainer's masked flat mixer on two bf16 mamba2-370m clients:
-    one kernel per leaf dtype, and with the two clients' f32 AdamW
-    moments resident the round still fits the chip."""
+    one kernel per leaf, and with the two clients' f32 AdamW moments
+    resident the round still fits the chip."""
     C = 2
     params = _stacked(params_shape, C, one_chip)
     mask = jax.ShapeDtypeStruct((C,), jnp.float32, sharding=one_chip)
     mixer = global_mixer("fedlay", build_permute_schedule(C, 3),
                          masked=True, fuse="flat")
     compiled, hlo = _compile(mixer, params, mask)
-    assert hlo.count("tpu_custom_call") == 2        # bf16 and f32 leaves
+    assert hlo.count("tpu_custom_call") == len(jax.tree.leaves(params))
     mem = compiled.memory_analysis()
     n = sum(int(np.prod(l.shape)) for l in jax.tree.leaves(params_shape))
     moments = 2 * C * n * 4
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert used + moments < 0.95 * HBM_BYTES, used + moments
+
+
+def _qwen3_4b_l3v8():
+    """``qwen3-4b-l3v8``: Qwen3-4B's widths, 3 layers, an eighth of the
+    vocabulary (the benchmark's one-chip cut)."""
+    return dataclasses.replace(REGISTRY["qwen3-4b"], num_layers=3,
+                               vocab_size=18992)
+
+
+#: Instructions that would move a leaf through HBM outside the kernels
+#: (the ravel's concatenate, a relayout's copy); above 2^16 elements
+#: each is a leaf of the models here, not the (C, C) matrix.
+RELAYOUT = re.compile(
+    r"= (\w+)\[([\d,]*)\]\S* (concatenate|dynamic-update-slice|copy)\(")
+
+
+@pytest.mark.parametrize("C", [2, 4])
+@pytest.mark.parametrize("model", ["mamba2-370m", "qwen3-4b-l3v8"])
+def test_leafwise_mixer_compiles_without_a_ravel(one_chip, compiled_kernels,
+                                                 model, C):
+    """The masked codec-free fused mixer at C ≤ LEAF_MIX_MAX_CLIENTS:
+    one kernel per leaf, each on the leaf in its default layout — no
+    population-sized concatenate, dynamic-update-slice or copy, and
+    temporaries under 64 MiB (the raveled buffer alone was ≥ 1.4 GB)."""
+    cfg = (REGISTRY[model] if model in REGISTRY else _qwen3_4b_l3v8())
+    shape = jax.eval_shape(lambda: init_params(
+        cfg, jax.random.PRNGKey(0), dtype=jnp.bfloat16))
+    params = _stacked(shape, C, one_chip)
+    mask = jax.ShapeDtypeStruct((C,), jnp.float32, sharding=one_chip)
+    edge = jax.ShapeDtypeStruct((C, 6), jnp.float32, sharding=one_chip)
+    mixer = global_mixer("fedlay", build_permute_schedule(C, 3),
+                         masked=True, fuse="flat")
+    compiled, hlo = _compile(lambda p, m, e: mixer(p, m, edge_mask=e),
+                             params, mask, edge)
+    assert hlo.count("tpu_custom_call") == len(jax.tree.leaves(params))
+    moved = [(op, dims) for _, dims, op in RELAYOUT.findall(hlo)
+             if np.prod([int(d) for d in dims.split(",") if d]) > 2 ** 16]
+    assert not moved, moved
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
 
 
 def test_flat_mixer_is_refused_across_chips(four_chips, params_shape,
